@@ -122,16 +122,5 @@ def record_bench(name: str, metrics: dict, path: str = None) -> str:
     return path
 
 
-def load_artifact(name: str):
-    """Previously-measured artifact, or None.  Engine measurements are
-    expensive on this 1-core container, so benchmark modules reuse their
-    artifacts when present (delete benchmarks/artifacts/ to re-measure)."""
-    path = os.path.join(ARTIFACTS, name)
-    if os.path.exists(path):
-        with open(path) as f:
-            return json.load(f)
-    return None
-
-
 def row(name: str, us: float, derived) -> dict:
     return {"name": name, "us_per_call": round(us, 1), "derived": derived}

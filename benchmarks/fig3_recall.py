@@ -9,17 +9,12 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core import AlignmentPolicy, ODMoEEngine
-from .common import (bench_model, bench_prompts, load_artifact, row,
-                     save_artifact, timed)
+from .common import bench_model, bench_prompts, row, save_artifact, timed
 
 SCHEMES = ("fp16", "int8", "nf4")
 
 
 def run(fast: bool = True):
-    cached = load_artifact("fig3_recall_curves.json")
-    if cached is not None:
-        return [row(f"fig3/{k.replace('_', '/')}", 0.0,
-                    float(np.mean(v))) for k, v in cached.items()]
     cfg, params = bench_model()
     n_tokens = 24 if fast else 64
     prompts = bench_prompts(cfg, q=2 if fast else 5)

@@ -8,14 +8,10 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core import AlignmentPolicy, ODMoEEngine
-from .common import (bench_model, bench_prompts, load_artifact, row,
-                     save_artifact, timed)
+from .common import bench_model, bench_prompts, row, save_artifact, timed
 
 
 def run(fast: bool = True):
-    cached = load_artifact("fig6_period_recall.json")
-    if cached is not None:
-        return [row(f"fig6/{label}", 0.0, r) for label, r in cached.items()]
     cfg, params = bench_model()
     periods = (1, 4, 16) if fast else (1, 2, 4, 8, 16)
     n_tokens = 24 if fast else 64

@@ -29,11 +29,6 @@ CASES = {
 
 
 def measure_recalls(fast: bool = True):
-    from .common import load_artifact
-    cached = load_artifact("fig8_ablation.json")
-    if cached is not None:
-        return cached["measured_recall"], {k: 0.0
-                                           for k in cached["measured_recall"]}
     cfg, params = bench_model()
     n_tokens = 24 if fast else 64
     prompts = bench_prompts(cfg, q=1 if fast else 4)

@@ -14,6 +14,8 @@ import time
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (decode_wallclock, fig3_recall, fig6_periods_recall,
                fig7_prefill, fig8_ablation, fig9_periods_speed,
                fleet_degradation, kv_occupancy, roofline,
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: " + ",".join(MODULES))
     args = ap.parse_args()
+    enable_compile_cache()
     names = (args.only.split(",") if args.only else list(MODULES))
     print("name,us_per_call,derived")
     failures = []

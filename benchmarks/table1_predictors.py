@@ -29,11 +29,6 @@ PAPER_REPORTED = {"AdapMoE": 0.86, "DAOP": 0.84, "HOBBIT": 0.91,
 
 
 def run(fast: bool = True):
-    from .common import load_artifact
-    cached = load_artifact("table1_predictors.json")
-    if cached is not None:
-        return [row(f"table1/{k}", 0.0, round(v, 4))
-                for k, v in cached["measured"].items()]
     cfg, params = bench_model()
     n_tokens = 24 if fast else 64
     prompts = bench_prompts(cfg, q=1 if fast else 5)

@@ -114,6 +114,10 @@ class TokenRecord:
 @dataclass
 class Trace:
     records: List[TokenRecord] = field(default_factory=list)
+    # with ``ODMoEEngine(keep_logits=True)``: each emitted token's
+    # (1 or B, V) logits, left on the device, one entry per record row
+    # (``repro.core.yardstick`` compares them to a float32 reference)
+    logits: List[jax.Array] = field(default_factory=list)
 
     def recall(self) -> Optional[float]:
         """Overall recall, Eq. (3), over the layers that HAD a
@@ -188,8 +192,11 @@ def _mixer_router_step(cfg: ModelConfig, kinds) -> object:
 
 @functools.lru_cache(maxsize=None)
 def _logits_argmax(cfg: ModelConfig) -> object:
-    return jax.jit(lambda p, x: jnp.argmax(
-        logits_from_hidden(cfg, p, x)[:, 0], axis=-1).astype(jnp.int32))
+    """Final norm + unembed + greedy pick: ``(token (B,), logits (B, V))``."""
+    def fn(p, x):
+        logits = logits_from_hidden(cfg, p, x)[:, 0]
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+    return jax.jit(fn)
 
 
 # ------------------------------------------------------- batch membership
@@ -242,7 +249,7 @@ class ODMoEEngine:
                  residency=None, peek_horizon: int = 0,
                  speculate: int = 1, sched=None, store=None,
                  gate_stats=None, compute_vs_ship=None,
-                 packed_slots: bool = False):
+                 packed_slots: bool = False, keep_logits: bool = False):
         if cfg.is_encoder_decoder:
             raise ValueError("engine drives decoder-only models")
         if wave_compute not in ("grouped", "loop"):
@@ -388,6 +395,11 @@ class ODMoEEngine:
         # the decode loop re-slicing them every token was pure overhead
         self._layer_params = [layer_params(cfg, self.params, li)
                               for li in range(cfg.num_layers)]
+        # (rows, V) logits of the latest decode step, left on the device;
+        # ``keep_logits`` also keeps every step's in the traces, which
+        # holds V floats per token on the device, so only checks ask
+        self.last_logits: Optional[jax.Array] = None
+        self.keep_logits = keep_logits
         self.predictor_kind = predictor
         self.shadow: Optional[SEPShadow] = None
         self.fly: Optional[GateExtrapolator] = None
@@ -486,6 +498,8 @@ class ODMoEEngine:
                 main_token, cache_list, pos, preds, n, rec)
             tokens_out.append(main_token)
             trace.records.append(rec)
+            if self.keep_logits:
+                trace.logits.append(self.last_logits)
         return jnp.stack(tokens_out, axis=1), trace
 
     def _generate_spec(self, batch, num_tokens: int,
@@ -604,7 +618,8 @@ class ODMoEEngine:
                                       h, topk_gate, x, rec)
         if self.prefetch is not None:
             self.prefetch.finish_token(step_idx)
-        return (_logits_argmax(cfg)(self.params, x), cache_list, pos + 1)
+        token, self.last_logits = _logits_argmax(cfg)(self.params, x)
+        return token, cache_list, pos + 1
 
     # ------------------------------------------------------- verify wave
     def decode_batch_spec(self, tokens, cache_list, pos, preds, step_idx,
@@ -668,7 +683,8 @@ class ODMoEEngine:
                                       h, topk_gate, x, rec)
         if self.prefetch is not None:
             self.prefetch.finish_token(step_idx)
-        verified = _logits_argmax(cfg)(self.params, x).reshape(b, s_w)
+        verified, self.last_logits = _logits_argmax(cfg)(self.params, x)
+        verified = verified.reshape(b, s_w)
         c = accept_prefix(tokens, verified)
         if max_commit is not None:
             c = jnp.minimum(c, jnp.asarray(max_commit, jnp.int32))
@@ -772,6 +788,7 @@ class ODMoEEngine:
             x = self._moe_bookkeeping(step_idx, li, moe_i, pending, true,
                                       h, topk_gate, x, rec)
         logits = logits_from_hidden(cfg, self.params, x)[:, 0]
+        self.last_logits = logits
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_list,
                 pos + 1)
 

@@ -2,8 +2,10 @@
 
 Each subpackage ships kernel.py (pl.pallas_call + BlockSpec VMEM tiling),
 ops.py (jit'd wrapper with CPU fallback), and ref.py (pure-jnp oracle).
-On this CPU container kernels validate in interpret=True mode; on TPU
-they run compiled.  See DESIGN.md §5 for why these four.
+On CPU the kernels are checked in interpret=True mode.  Only the MoE
+grouped-GEMM kernels (fp32/bf16 and int8-packed) are compiled for TPU
+v5e (tests/test_chip_compile.py); nf4-packed, flash_decode, int8_matmul
+and ssd_scan have never been compiled for a chip.
 """
 from .flash_decode import flash_decode, flash_decode_kernel, flash_decode_ref
 from .int8_matmul import int8_matmul, int8_matmul_kernel, int8_matmul_ref

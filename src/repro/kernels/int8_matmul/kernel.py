@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 def _make_kernel(n_k: int, total_k: int, block_k: int):

@@ -28,7 +28,7 @@ def _on_tpu() -> bool:
 
 
 def moe_ffn(xd, w_gate, w_up, w_down, *, block_c: int = 128,
-            block_f: int = 512, force_kernel: bool = False,
+            block_f=None, force_kernel: bool = False,
             interpret: bool | None = None):
     """Grouped expert FFN; see kernel.py for the tiling contract."""
     if interpret is None:
@@ -40,7 +40,7 @@ def moe_ffn(xd, w_gate, w_up, w_down, *, block_c: int = 128,
 
 
 def moe_ffn_packed(xd, parts, *, scheme: str, block_c: int = 128,
-                   block_f: int = 512, force_kernel: bool = False,
+                   block_f=None, force_kernel: bool = False,
                    interpret: bool | None = None):
     """Grouped expert FFN on WIRE-format stacked weights (the packed-
     weights carrier): ``parts`` maps w_gate/w_up/w_down to device-layout

@@ -1,9 +1,10 @@
 """Packed-weight grouped expert-FFN Pallas kernel (in-kernel dequant).
 
 The packed sibling of kernel.py: identical ``(E, C/Cb, F/Fb)`` grid,
-ragged-F masking and fp32-accumulator contract, but the weight operands
-arrive in WIRE format — fp16 halves, int8 codes + per-channel scales,
-or bit-packed nf4 codes + per-block absmax — and are dequantized
+tiling rule (``pick_tiles``, counting weight tiles at their wire
+width), ragged-F masking and fp32-accumulator contract, but the weight
+operands arrive in WIRE format — fp16 halves, int8 codes + per-channel
+scales, or bit-packed nf4 codes + per-block absmax — and are dequantized
 in-register immediately before the MXU dots.  HBM->VMEM therefore
 streams packed tiles (2x / 4x / ~8x fewer weight bytes than the fp32
 kernel), which is where OD-MoE's Eq. (1) bandwidth term actually goes.
@@ -28,6 +29,13 @@ Tile layout (see ``repro.quant.transport.device_layout``):
     of ``NF4_BLOCK`` — the wrapper enforces ``block_f % 64 == 0`` and a
     64-aligned logical f (misaligned shapes use the dequantize-on-
     arrival fallback upstream, never this kernel).
+
+int8 compiles for TPU v5e at Granite and Mixtral widths.  nf4 does
+not: its ``(d, Fb/64)`` absmax block has a lane dim that is
+neither a multiple of 128 nor the full dim at Mixtral widths, which the
+Pallas TPU lowering refuses, and where it is the full dim (Granite) the
+Mosaic compile does not finish.  nf4 runs in interpret mode only
+(tests/test_chip_compile.py pins the refusal).
 """
 from __future__ import annotations
 
@@ -35,22 +43,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
+
+from .kernel import _mask_ragged_f, pick_tiles
 
 _PARTS = {"fp16": 1, "int8": 2, "nf4": 2}
-_NF4_BLOCK = 64          # == repro.quant.quantize.NF4_BLOCK (import cycle)
-_NF4_TABLE = None        # NF4_LEVELS as python floats, filled lazily
-
-
-def _nf4_table():
-    global _NF4_TABLE
-    if _NF4_TABLE is None:
-        from repro.quant.quantize import NF4_BLOCK, NF4_LEVELS
-        assert NF4_BLOCK == _NF4_BLOCK
-        _NF4_TABLE = tuple(float(v) for v in np.asarray(NF4_LEVELS))
-    return _NF4_TABLE
+# bytes one weight element streams at (codes; the scale rows are small)
+_WIRE_BYTES = {"fp16": 2, "int8": 1, "nf4": 0.5 + 4 / 64}
+# The 16 NormalFloat-4 levels of QLoRA (Dettmers et al., 2023) and the
+# absmax block length.  Python floats, so the kernel trace closes over
+# constants; ``repro.quant.quantize`` builds its NF4_LEVELS from these.
+NF4_TABLE = (
+    -1.0, -0.6961928009986877, -0.5250730514526367, -0.39491748809814453,
+    -0.28444138169288635, -0.18477343022823334, -0.09105003625154495, 0.0,
+    0.07958029955625534, 0.16093020141124725, 0.24611230194568634,
+    0.33791524171829224, 0.44070982933044434, 0.5626170039176941,
+    0.7229568362236023, 1.0)
+NF4_BLOCK = 64
 
 
 def _dequant_tile(scheme: str, refs):
@@ -64,7 +74,7 @@ def _dequant_tile(scheme: str, refs):
     # branch-free LUT on the VPU, then the per-64-block absmax.  Exactly
     # one where-arm matches per element, so this reproduces
     # NF4_LEVELS[code] * absmax bit-for-bit.
-    table = _nf4_table()
+    table = NF4_TABLE
     c = refs[0][0].astype(jnp.int32)                  # (R, Cb/2)
     hi = (c >> 4) & 0xF
     lo = c & 0xF
@@ -73,7 +83,7 @@ def _dequant_tile(scheme: str, refs):
     levels = jnp.full(idx.shape, table[0], jnp.float32)
     for v in range(1, 16):
         levels = jnp.where(idx == v, table[v], levels)
-    scales = jnp.repeat(refs[1][0], _NF4_BLOCK, axis=-1)
+    scales = jnp.repeat(refs[1][0], NF4_BLOCK, axis=-1)
     return levels * scales
 
 
@@ -90,11 +100,8 @@ def _make_packed_kernel(scheme: str, total_f: int, block_f: int):
         wd = _dequant_tile(scheme, w[2 * npart:])      # (Fb, D)
         # same ragged-F zeroing as the fp32 kernel: an out-of-bounds
         # final tile dequantizes padding garbage, masked before the dots
-        fmask = (fi * block_f + jax.lax.iota(jnp.int32, block_f)
-                 < total_f)
-        wg = jnp.where(fmask[None, :], wg, 0)
-        wu = jnp.where(fmask[None, :], wu, 0)
-        wd = jnp.where(fmask[:, None], wd, 0)
+        if total_f % block_f:
+            wg, wu, wd = _mask_ragged_f(fi, total_f, block_f, wg, wu, wd)
         h = jax.nn.silu(jnp.dot(x, wg, preferred_element_type=jnp.float32))
         u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
         y = jnp.dot((h * u).astype(x.dtype), wd,
@@ -127,11 +134,11 @@ def _weight_specs(scheme: str, d: int, bf: int):
     elif scheme == "nf4":
         up = [pl.BlockSpec((1, d, bf // 2),
                            lambda e_, ci, fi: (e_, 0, fi)),
-              pl.BlockSpec((1, d, bf // _NF4_BLOCK),
+              pl.BlockSpec((1, d, bf // NF4_BLOCK),
                            lambda e_, ci, fi: (e_, 0, fi))]
         down = [pl.BlockSpec((1, bf, d // 2),
                              lambda e_, ci, fi: (e_, fi, 0)),
-                pl.BlockSpec((1, bf, d // _NF4_BLOCK),
+                pl.BlockSpec((1, bf, d // NF4_BLOCK),
                              lambda e_, ci, fi: (e_, fi, 0))]
     return up + up + down
 
@@ -145,7 +152,7 @@ def packed_logical_f(scheme: str, parts) -> int:
 @functools.partial(jax.jit, static_argnames=("scheme", "block_c",
                                              "block_f", "interpret"))
 def moe_ffn_packed_kernel(xd, parts, *, scheme: str, block_c: int = 128,
-                          block_f: int = 512, interpret: bool = False):
+                          block_f=None, interpret: bool = False):
     """xd: (E, C, D) -> (E, C, D) on wire-format stacked weights.
 
     ``parts`` maps w_gate/w_up/w_down to their device-layout part
@@ -157,10 +164,11 @@ def moe_ffn_packed_kernel(xd, parts, *, scheme: str, block_c: int = 128,
         raise ValueError(f"no packed kernel for scheme {scheme!r}")
     e, c, d = xd.shape
     f = packed_logical_f(scheme, parts)
-    bc = min(block_c, c)
-    bf = min(block_f, f)
-    if scheme == "nf4" and (f % _NF4_BLOCK or bf % _NF4_BLOCK
-                            or d % _NF4_BLOCK):
+    bc, bf = pick_tiles(c, d, f, x_bytes=xd.dtype.itemsize,
+                        w_bytes=_WIRE_BYTES[scheme], block_c=block_c,
+                        block_f=block_f)
+    if scheme == "nf4" and (f % NF4_BLOCK or bf % NF4_BLOCK
+                            or d % NF4_BLOCK):
         raise ValueError("nf4 packed kernel needs f, d and block_f "
                          "aligned to the 64-element absmax block; "
                          f"got f={f}, d={d}, block_f={bf}")

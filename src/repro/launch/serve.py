@@ -18,6 +18,12 @@ placement and compute-vs-ship wave scheduling:
   PYTHONPATH=src python -m repro.launch.serve --requests 16 \
       --replicas 2 --placement gate-stats --compute-vs-ship
 
+Published widths (``--full``; the default ``--reduced`` is the small
+same-family CPU model), in the config's own dtype, cut to ``--layers``:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-moe-3b-a800m \
+      --full --layers 8 --tokens 16
+
 Both run real prefill+decode through ``ODMoEEngine`` (prediction,
 on-demand loading, alignment, eviction — all live) and verify outputs
 match the dense reference bit-for-bit.  Serving mode drives Poisson
@@ -29,6 +35,7 @@ throughput from the timing model, alongside load-amortization stats
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -37,11 +44,14 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import (AlignmentPolicy, ODMoEEngine, RTX3090_EDGE,
                         node_memory_report, simulate_cached, simulate_odmoe)
+from repro.core.yardstick import check_decode, merge
 from repro.fleet import (FleetSchedule, GateStatsRecorder,
                          expected_t_maxload, modulo_plan,
                          optimize_placement)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import greedy_generate, init_params
-from repro.quant import TieredPolicy, UniformPolicy
+from repro.quant import (TieredPolicy, UniformPolicy, resolve_policy,
+                         transport_params)
 from repro.serve import (BatchComposer, KVPool, ServingLoop, WorkloadSpec,
                          dense_cache_footprint, make_cluster, make_trace,
                          make_traffic)
@@ -51,6 +61,13 @@ from repro.serve.cluster import ROUTING_POLICIES
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="small same-family fp32 model (the default)")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the config's published widths and dtype")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: keep the first N layers (0 = the "
+                         "config's own depth)")
     ap.add_argument("--tokens", type=int, default=24,
                     help="decode length (serving: max new tokens/request)")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -219,7 +236,7 @@ def engine_kwargs(cfg, params, args, transport) -> dict:
     placement schedule and compute-vs-ship pricing."""
     kw = dict(predictor=args.predictor, shadow_scheme=args.shadow,
               transport=transport, speculate=args.speculate,
-              packed_slots=args.packed_slots)
+              packed_slots=args.packed_slots, keep_logits=True)
     sched = build_placement(cfg, params, args)
     if sched is not None:
         kw["sched"] = sched
@@ -244,18 +261,38 @@ def build_requests(cfg, args):
                         max_new=args.tokens, seed=args.seed)
 
 
-def check_bit_exact(cfg, params, reqs, outputs, transport) -> None:
+def yardstick_ok(cfg, params, transport, runs) -> bool:
+    """Token bit-identity with ``greedy_generate`` needs shared
+    executables, which a TPU run does not have: judge the requests
+    against the float32 reference instead (``repro.core.yardstick``).
+    ``runs`` holds ``(prompt, tokens, records, logits)`` per request."""
+    if not resolve_policy(transport).trivial:
+        params = transport_params(cfg, params, transport)
+    rep = merge([check_decode(cfg, params, *run) for run in runs])
+    print(f"  vs float32 reference: {rep.describe()}")
+    return rep.ok
+
+
+def check_bit_exact(cfg, params, reqs, outputs, transport,
+                    states=None) -> None:
     """Every served request must match its solo reference decode under
-    the SAME transport policy — the cross-cutting correctness bar."""
-    exact = True
+    the SAME transport policy — the cross-cutting correctness bar.  With
+    the requests' ``states`` (their per-step traces), requests whose
+    tokens differ may still pass the float32 yardstick."""
+    differ = []
     for r in reqs:
         ref = np.asarray(greedy_generate(
             cfg, params, {"tokens": jnp.asarray(r.prompt)[None, :]},
             r.max_new_tokens, transport=transport))[0]
-        exact &= bool(np.array_equal(ref, outputs[r.rid]))
+        if not np.array_equal(ref, outputs[r.rid]):
+            differ.append(r)
     print(f"  per-request tokens == solo reference "
-          f"(same transport policy): {exact}")
-    assert exact, "serving output diverged from single-request reference"
+          f"(same transport policy): {not differ}")
+    ok = not differ or (states is not None and yardstick_ok(
+        cfg, params, transport,
+        [(r.prompt, outputs[r.rid], states[r.rid].trace.records,
+          states[r.rid].trace.logits) for r in differ]))
+    assert ok, "serving output diverged from single-request reference"
 
 
 def serve_cluster(cfg, params, args) -> None:
@@ -290,7 +327,9 @@ def serve_cluster(cfg, params, args) -> None:
           f"{sum(gate_stats.rows.values())} routed rows")
 
 
-def serve_traffic(cfg, params, args) -> None:
+def run_traffic(cfg, params, args):
+    """Serve ``build_requests`` traffic through one ``ServingLoop``:
+    returns ``(requests, result, engine, transport, kv_pool)``."""
     transport = build_transport(cfg, params, args)
     eng = ODMoEEngine(cfg, params,
                       **engine_kwargs(cfg, params, args, transport))
@@ -304,8 +343,12 @@ def serve_traffic(cfg, params, args) -> None:
                                               kv_pool=kv_pool),
                        policy=policy, kv_pool=kv_pool,
                        preempt=args.preempt, admit=args.admit)
-    res = loop.run(reqs)
-    check_bit_exact(cfg, params, reqs, res.outputs, transport)
+    return reqs, loop.run(reqs), eng, transport, kv_pool
+
+
+def serve_traffic(cfg, params, args) -> None:
+    reqs, res, eng, transport, kv_pool = run_traffic(cfg, params, args)
+    check_bit_exact(cfg, params, reqs, res.outputs, transport, res.states)
     # ---- latency / throughput report (modeled edge profile)
     rep = res.timings.report()
     print(f"  requests: {rep['n_requests']}  tokens: {rep['total_tokens']}"
@@ -377,7 +420,9 @@ def serve_traffic(cfg, params, args) -> None:
               f"max {max(vals) / 1e6:.2f} MB")
 
 
-def serve_single(cfg, params, args) -> None:
+def run_single(cfg, params, args):
+    """Single-stream decode of one seeded prompt: returns
+    ``(batch, tokens, trace, engine, transport)``."""
     key = jax.random.PRNGKey(args.seed)
     batch = {"tokens": jax.random.randint(key, (1, args.prompt_len), 0,
                                           cfg.vocab_size)}
@@ -386,11 +431,20 @@ def serve_single(cfg, params, args) -> None:
                       **engine_kwargs(cfg, params, args, transport))
     policy = AlignmentPolicy(args.token_period, args.kv_period)
     toks, trace = eng.generate(batch, args.tokens, policy)
+    return batch, toks, trace, eng, transport
+
+
+def serve_single(cfg, params, args) -> None:
+    batch, toks, trace, eng, transport = run_single(cfg, params, args)
     ref = greedy_generate(cfg, params, batch, args.tokens,
                           transport=transport)
     exact = bool(np.array_equal(np.asarray(toks), np.asarray(ref)))
     print(f"  tokens == dense reference (same transport policy): {exact}")
-    assert exact, "engine output diverged from reference"
+    assert exact or yardstick_ok(
+        cfg, params, transport, [(np.asarray(batch["tokens"])[0],
+                                  np.asarray(toks)[0], trace.records,
+                                  trace.logits)]), \
+        "engine output diverged from reference"
     rec = trace.recall()      # None when nothing was predicted
     print(f"  recall (Eq.3): "
           f"{'n/a (no predictions)' if rec is None else f'{rec:.4f}'}   "
@@ -414,9 +468,20 @@ def serve_single(cfg, params, args) -> None:
           f"(fully-cached reference {simulate_cached(cfg, RTX3090_EDGE):.2f})")
 
 
+def build_config(args):
+    """``--arch`` at reduced or published widths, cut to ``--layers``."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
 def main():
     args = build_parser().parse_args()
-    cfg = get_config(args.arch).reduced()
+    enable_compile_cache()
+    cfg = build_config(args)
     if not cfg.num_experts:
         raise SystemExit(f"{args.arch} has no experts — OD-MoE loading is "
                          "inapplicable (see DESIGN.md §4); serve it with "
@@ -430,7 +495,8 @@ def main():
             + (f", {args.replicas} replicas ({args.routing})"
                if args.replicas > 1 else "")
             if args.requests else "single stream")
-    print(f"[serve] {cfg.name}: E={cfg.num_experts} top{cfg.top_k}, "
+    print(f"[serve] {cfg.name}: d={cfg.d_model} L={cfg.num_layers} "
+          f"{cfg.dtype}, E={cfg.num_experts} top{cfg.top_k}, "
           f"{args.workers} workers, predictor={args.predictor}"
           + (f"/{args.shadow}" if args.predictor == "sep" else "")
           + f", transport={args.transport_precision}"

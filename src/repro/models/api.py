@@ -215,6 +215,15 @@ def decode_step(cfg: ModelConfig, params, token, state, *,
     return logits, new_state
 
 
+@functools.lru_cache(maxsize=None)
+def _jit_decode_step(cfg: ModelConfig, moe_method: str):
+    """One compiled decode step per (config, dispatch).  Called eagerly,
+    ``lm_decode`` re-traces its layer scan on every token, and each
+    trace is a fresh XLA compile."""
+    return jax.jit(functools.partial(decode_step, cfg,
+                                     moe_method=moe_method))
+
+
 def greedy_generate(cfg: ModelConfig, params, batch, num_tokens: int,
                     max_cache_len: int = 0, moe_method: str = "grouped",
                     transport=None):
@@ -241,9 +250,10 @@ def greedy_generate(cfg: ModelConfig, params, batch, num_tokens: int,
                             moe_method=moe_method)
     token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     out = [token]
+    step = _jit_decode_step(cfg, moe_method)
     for _ in range(num_tokens - 1):
-        logits, state = decode_step(cfg, params, token, state,
-                                    moe_method=moe_method)
+        logits, state = step(params, token, state)
         token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         out.append(token)
     return jnp.stack(out, axis=1)
+

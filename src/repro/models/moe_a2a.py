@@ -28,7 +28,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .config import ModelConfig
 from .moe import route
@@ -149,8 +148,8 @@ def make_moe_a2a(mesh, cap_factor: float = 1.25):
                     P("model", None, None))
         out_specs = (P(dp_axes, None),
                      {"load_balance_loss": P(), "topk_idx": P(dp_axes, None)})
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(x, params["router"], params["w_gate"], params["w_up"],
                   params["w_down"])
 

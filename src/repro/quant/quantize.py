@@ -23,15 +23,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-# The 16 NormalFloat-4 levels from QLoRA (Dettmers et al., 2023).
-NF4_LEVELS = jnp.array([
-    -1.0, -0.6961928009986877, -0.5250730514526367, -0.39491748809814453,
-    -0.28444138169288635, -0.18477343022823334, -0.09105003625154495, 0.0,
-    0.07958029955625534, 0.16093020141124725, 0.24611230194568634,
-    0.33791524171829224, 0.44070982933044434, 0.5626170039176941,
-    0.7229568362236023, 1.0], dtype=jnp.float32)
+from repro.kernels.moe_gemm.packed import NF4_BLOCK, NF4_TABLE
 
-NF4_BLOCK = 64
+NF4_LEVELS = jnp.array(NF4_TABLE, dtype=jnp.float32)
 
 
 # ----------------------------------------------------------------- int8

@@ -729,6 +729,9 @@ class ServingLoop:
             sliced = self._slice_record(rec, lo, lo + ci)
             sliced.index = len(state.generated) - ci  # wave-start n
             state.trace.records.append(sliced)
+            if eng.keep_logits:
+                state.trace.logits.extend(
+                    eng.last_logits[lo + j:lo + j + 1] for j in range(ci))
 
     @staticmethod
     def _slice_record(rec: TokenRecord, lo: int, hi: int) -> TokenRecord:
