@@ -1,0 +1,94 @@
+"""A new configuration, traffic mix and per-layer metric are new files,
+found by name: no file that is already there changes."""
+from __future__ import annotations
+
+import json
+import shutil
+
+import pytest
+
+import chipbench_tiny
+from chipbench import config, spec, traffic
+
+
+@pytest.fixture
+def tree(tmp_path):
+    spec_file = chipbench_tiny.build(tmp_path)
+    before = {p: p.read_bytes() for p in tmp_path.rglob("*") if p.is_file()}
+    return tmp_path, spec_file, before
+
+
+def test_new_cell_is_found_by_name(tree):
+    base, spec_file, before = tree
+    cfg = dict(chipbench_tiny.CONFIG, name="tiny-wide", hidden_size=128)
+    (base / "configs" / "tiny-wide.json").write_text(json.dumps(cfg))
+    mix = dict(chipbench_tiny.MIX, name="pairs", clients=2, max_batch=2)
+    (base / "traffic" / "pairs.json").write_text(json.dumps(mix))
+    (base / "metrics" / "mean_prompt.py").write_text(
+        "def read(run):\n"
+        "    n = [len(c.req.prompt) for c in run.clients]\n"
+        "    return sum(n) / len(n) if n else None\n")
+    raw = json.loads(spec_file.read_text())
+    raw["workloads"].append({"name": "tiny-wide.pairs",
+                             "config": "tiny-wide", "traffic": "pairs",
+                             "chips": 1, "why": "a new cell"})
+    raw["per_layer"].append(dict(chipbench_tiny.metric("mean_prompt",
+                                                       "tokens"),
+                                 workloads=["tiny-wide.pairs"]))
+    spec_file.write_text(json.dumps(raw))
+
+    cell = spec.load_cell("tiny-wide.pairs", spec_file)
+    assert (cell.config, cell.traffic) == ("tiny-wide", "pairs")
+    assert "mean_prompt" in [m.name for m in cell.per_layer]
+    assert "mean_prompt" not in [m.name for m in
+                                 spec.load_cell("tiny.solo",
+                                                spec_file).per_layer]
+    assert config.load("tiny-wide", base).model.d_model == 128
+    m = traffic.Mix.from_dict(spec.load_json("traffic", "pairs", base))
+    assert m.clients == 2
+    fake = type("Run", (), {"clients": [type("C", (), {"req": r})()
+                                        for r in traffic.make_requests(
+                                            m, 512, 3)[:4]]})()
+    new = [m for m in cell.per_layer if m.name == "mean_prompt"]
+    got = spec.read_metrics(new, fake, base)
+    assert got["mean_prompt"]["unit"] == "tokens"
+    after = {p: p.read_bytes() for p in before}
+    changed = [p.name for p in before
+               if p != spec_file and after[p] != before[p]]
+    assert changed == []
+
+
+def test_reader_that_finds_nothing_leaves_the_metric_out(tree):
+    base, spec_file, _ = tree
+    cell = spec.load_cell("tiny.solo", spec_file)
+    empty = type("Run", (), {"counters": {"loads": 0, "decoded_tokens": 0},
+                             "records": [], "steps": []})()
+    assert spec.read_metrics(list(cell.per_layer), empty, base) == {}
+
+
+def test_unknown_names_are_errors(tree):
+    base, spec_file, _ = tree
+    with pytest.raises(KeyError):
+        spec.load_cell("tiny.nothing", spec_file)
+    with pytest.raises(FileNotFoundError):
+        config.load("no-such-model", base)
+    with pytest.raises(FileNotFoundError):
+        spec.metric_reader("no_such_metric", base)
+
+
+def test_the_real_benchmark_files_resolve():
+    for w in json.loads(spec.find_spec_file().read_text())["workloads"]:
+        cell = spec.load_cell(w["name"])
+        config.load(cell.config)
+        traffic.Mix.from_dict(spec.load_json("traffic", cell.traffic))
+        spec.load_json("limits", cell.name)
+        for m in cell.per_layer:
+            spec.metric_reader(m.name)
+        assert "setup_s" in [m.name for m in cell.end_to_end]
+
+
+def test_bench_dir_alone_is_enough(tmp_path):
+    """The package finds everything under its own directory."""
+    shutil.copytree(spec.BENCH_DIR / "configs", tmp_path / "configs")
+    assert config.load("granite3-3b-a800m-8L", tmp_path).model.num_layers \
+        == 8

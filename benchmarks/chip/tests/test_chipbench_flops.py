@@ -1,0 +1,70 @@
+"""Operation and byte counts, checked by hand for Granite-3.0-3B-A800M."""
+from __future__ import annotations
+
+import pytest
+
+import chipbench_tiny  # noqa: F401
+from chipbench import config, flops, peaks
+
+D, F = 1536, 512          # hidden size, expert width
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return config.load("granite3-3b-a800m-8L").model
+
+
+def test_active_flops_by_hand(cfg):
+    # per layer: q (1536x1536) + k, v (1536x512 each) + o (1536x1536)
+    # = 6,291,456 weights; router 1536x40 = 61,440; 8 experts x 3 x
+    # 1536 x 512 = 18,874,368 -> 25,227,264 weights x 2 FLOPs
+    per_layer = 2 * (6_291_456 + 61_440 + 18_874_368)
+    head = 2 * 1536 * 49155
+    assert flops.active_matmul_flops(cfg) == 8 * per_layer + head
+
+
+def test_attention_and_prompt_flops_by_hand(cfg):
+    # 24 heads x 64 dims, QK and PV: 4 x 1536 FLOPs per key and layer
+    assert flops.attention_flops(cfg, 100) == 8 * 4 * 1536 * 100
+    n = 3
+    assert flops.prompt_flops(cfg, n) == (
+        3 * flops.active_matmul_flops(cfg) + 8 * 4 * 1536 * (1 + 2 + 3))
+    assert flops.decode_flops(cfg, 10) == (
+        flops.active_matmul_flops(cfg) + 8 * 4 * 1536 * 10)
+
+
+def test_decode_wave_roofline_by_hand():
+    # one row, 8 routed experts: 8 pairs x 6 x 1536 x 512 FLOPs, and the
+    # 8 experts' bf16 weights (3 x 1536 x 512 x 2 bytes each) read once
+    fl, by = flops.gemm_call_work(D, F, 2, pairs=8, experts=8, rows=1)
+    assert fl == 8 * 6 * D * F == 37_748_736
+    assert by == 8 * 3 * D * F * 2 + 2 * D * 2 == 37_754_880
+    p = peaks.for_kind("TPU v5 lite")
+    t, bound = flops.least_time_s(fl, by, p.bf16_flops, p.hbm_bytes_s)
+    assert bound == "memory"
+    assert t == pytest.approx(37_754_880 / 819e9)        # ~46.1 us
+
+
+def test_prefill_roofline_is_compute_bound_at_long_prompts():
+    # 2048 rows x 8 pairs through all 40 experts
+    fl, by = flops.gemm_call_work(D, F, 2, pairs=2048 * 8, experts=40,
+                                  rows=2048)
+    p = peaks.for_kind("TPU v5 lite")
+    t, bound = flops.least_time_s(fl, by, p.bf16_flops, p.hbm_bytes_s)
+    assert bound == "compute"
+    assert t == pytest.approx(2048 * 8 * 6 * D * F / 197e12)
+
+
+def test_wave_calls_count_pairs_and_distinct_experts():
+    import numpy as np
+    from types import SimpleNamespace as NS
+    lr = NS(true=np.array([[1, 2], [2, 3]]),
+            waves=[[(1, 0), (2, 1)], [(3, 0)]])
+    calls = list(flops.wave_calls([NS(layers=[lr])], D, F, 2))
+    assert calls[0] == flops.gemm_call_work(D, F, 2, 3, 2, 2)
+    assert calls[1] == flops.gemm_call_work(D, F, 2, 1, 1, 2)
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError):
+        peaks.for_kind("TPU v9 imaginary")
