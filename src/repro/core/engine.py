@@ -67,6 +67,7 @@ from .specdecode import (_spec_block_step, _spec_mixer_router_step,
                          accept_prefix, select_commit, wave_preds)
 from .prefetch import PrefetchExecutor, make_executor, resolve_residency
 from .schedule import GroupSchedule
+from .spans import joined, span
 from .store import ExpertStore, WorkerSlots
 
 
@@ -184,8 +185,9 @@ def _mixer_router_step(cfg: ModelConfig, kinds) -> object:
     def fn(lp, x, cache, pos):
         x, cache, _ = block_decode(cfg, lp, (kinds[0], NO_FF), x, cache,
                                    pos)
-        h = apply_norm(cfg, x, lp["norm2"])[:, 0]          # router input
-        topk_idx, topk_gate, _ = route(cfg, lp["ff"], h)
+        with jax.named_scope("router"):
+            h = apply_norm(cfg, x, lp["norm2"])[:, 0]      # router input
+            topk_idx, topk_gate, _ = route(cfg, lp["ff"], h)
         return x, cache, h, topk_idx, topk_gate
     return jax.jit(fn)
 
@@ -194,8 +196,9 @@ def _mixer_router_step(cfg: ModelConfig, kinds) -> object:
 def _logits_argmax(cfg: ModelConfig) -> object:
     """Final norm + unembed + greedy pick: ``(token (B,), logits (B, V))``."""
     def fn(p, x):
-        logits = logits_from_hidden(cfg, p, x)[:, 0]
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+        with jax.named_scope("logits"):
+            logits = logits_from_hidden(cfg, p, x)[:, 0]
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
     return jax.jit(fn)
 
 
@@ -453,17 +456,18 @@ class ODMoEEngine:
         have reserved ``pages_for(prompt_len)`` pages (admission
         control) and supplies the request id the page table is keyed by.
         """
-        logits, state = prefill(self.cfg, self.params, batch, max_cache_len,
-                                moe_method="grouped")
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        cache_list = self._unstack(state["caches"])
-        if kv_pool is not None:
-            if batch["tokens"].shape[0] != 1 or rid is None:
-                raise ValueError("paged prefill adopts one request (B=1) "
-                                 "with its request id")
-            cache_list = kv_pool.adopt(rid, cache_list,
-                                       batch["tokens"].shape[1])
-        return token, cache_list, state["pos"]
+        with span("prefill", prompt_len=int(batch["tokens"].shape[1])):
+            logits, state = prefill(self.cfg, self.params, batch,
+                                    max_cache_len, moe_method="grouped")
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            cache_list = self._unstack(state["caches"])
+            if kv_pool is not None:
+                if batch["tokens"].shape[0] != 1 or rid is None:
+                    raise ValueError("paged prefill adopts one request "
+                                     "(B=1) with its request id")
+                cache_list = kv_pool.adopt(rid, cache_list,
+                                           batch["tokens"].shape[1])
+            return token, cache_list, state["pos"]
 
     # ------------------------------------------------------------ generate
     def generate(self, batch, num_tokens: int,
@@ -588,38 +592,42 @@ class ODMoEEngine:
         producing bit-identical tokens by the shared-arithmetic
         contract.
         """
-        if self.wave_compute == "loop":
-            return self._decode_batch_loop(token, cache_list, pos, preds,
-                                           step_idx, rec)
-        cfg = self.cfg
-        if self.faults is not None:
-            self.faults.apply(step_idx, self.sched.state, self.slots)
-        x = _embed_token(self.params, token)
-        pending: Dict[int, np.ndarray] = dict(preds)
-        # SEP predictions cover the whole token up front: queue their
-        # fetches NOW so transfers overlap all the compute before each
-        # layer's wave boundary (the peek horizon bounds the window)
-        if self.prefetch is not None and pending:
-            self.prefetch.enqueue(step_idx, 0, pending,
-                                  skip=self._resident_skip())
-        moe_i = -1
-        for li, kinds in enumerate(cfg.layer_kinds()):
-            lp = self._layer_params[li]
-            if kinds[1] != MOE_FF:
-                x, cache_list[li], _ = _block_step(cfg, kinds)(
-                    lp, x, cache_list[li], pos)
-                continue
-            moe_i += 1
-            # mixer + residual + router input + gate, one jitted dispatch
-            x, cache_list[li], h, topk_idx, topk_gate = _mixer_router_step(
-                cfg, kinds)(lp, x, cache_list[li], pos)
-            true = np.asarray(topk_idx)
-            x = self._moe_bookkeeping(step_idx, li, moe_i, pending, true,
-                                      h, topk_gate, x, rec)
-        if self.prefetch is not None:
-            self.prefetch.finish_token(step_idx)
-        token, self.last_logits = _logits_argmax(cfg)(self.params, x)
-        return token, cache_list, pos + 1
+        with span("decode_step", step=step_idx, rows=int(token.shape[0]),
+                  rids=joined(self.slots.request_context)):
+            if self.wave_compute == "loop":
+                return self._decode_batch_loop(token, cache_list, pos,
+                                               preds, step_idx, rec)
+            cfg = self.cfg
+            if self.faults is not None:
+                self.faults.apply(step_idx, self.sched.state, self.slots)
+            x = _embed_token(self.params, token)
+            pending: Dict[int, np.ndarray] = dict(preds)
+            # SEP predictions cover the whole token up front: queue their
+            # fetches NOW so transfers overlap all the compute before each
+            # layer's wave boundary (the peek horizon bounds the window)
+            if self.prefetch is not None and pending:
+                self.prefetch.enqueue(step_idx, 0, pending,
+                                      skip=self._resident_skip())
+            moe_i = -1
+            for li, kinds in enumerate(cfg.layer_kinds()):
+                lp = self._layer_params[li]
+                if kinds[1] != MOE_FF:
+                    x, cache_list[li], _ = _block_step(cfg, kinds)(
+                        lp, x, cache_list[li], pos)
+                    continue
+                moe_i += 1
+                # mixer + residual + router input + gate, one dispatch
+                x, cache_list[li], h, topk_idx, topk_gate = \
+                    _mixer_router_step(cfg, kinds)(lp, x, cache_list[li],
+                                                   pos)
+                with span("router_sync", layer=li):
+                    true = np.asarray(topk_idx)
+                x = self._moe_bookkeeping(step_idx, li, moe_i, pending,
+                                          true, h, topk_gate, x, rec)
+            if self.prefetch is not None:
+                self.prefetch.finish_token(step_idx)
+            token, self.last_logits = _logits_argmax(cfg)(self.params, x)
+            return token, cache_list, pos + 1
 
     # ------------------------------------------------------- verify wave
     def decode_batch_spec(self, tokens, cache_list, pos, preds, step_idx,
@@ -651,50 +659,54 @@ class ODMoEEngine:
             rec.spec_len, rec.committed = 1, b   # uniform accounting
             return (tok[:, None], jnp.ones((b,), jnp.int32), cache_list,
                     pos)
-        if self.faults is not None:
-            self.faults.apply(step_idx, self.sched.state, self.slots)
-        x = _embed_token(self.params, tokens.reshape(-1))
-        pos_rows = (pos[:, None]
-                    + jnp.arange(s_w, dtype=pos.dtype)).reshape(-1)
-        pending: Dict[int, np.ndarray] = dict(preds)
-        if self.prefetch is not None and pending:
-            self.prefetch.enqueue(step_idx, 0, pending,
-                                  skip=self._resident_skip())
-        spec_caches: Dict[int, dict] = {}
-        moe_i = -1
-        for li, kinds in enumerate(cfg.layer_kinds()):
-            lp = self._layer_params[li]
-            # each wave row verifies against its own copy of the
-            # request's cache (seeded with the earlier rows' K/V inside
-            # the spec step); the commit below SELECTS the accepted
-            # row, so nothing is written back until acceptance
-            repl = jax.tree.map(lambda a: jnp.repeat(a, s_w, axis=0),
-                                cache_list[li])
-            if kinds[1] != MOE_FF:
-                x, spec_caches[li] = _spec_block_step(cfg, kinds, s_w)(
-                    lp, x, repl, pos_rows)
-                continue
-            moe_i += 1
-            x, spec_caches[li], h, topk_idx, topk_gate = \
-                _spec_mixer_router_step(cfg, kinds, s_w)(
-                    lp, x, repl, pos_rows)
-            true = np.asarray(topk_idx)
-            x = self._moe_bookkeeping(step_idx, li, moe_i, pending, true,
-                                      h, topk_gate, x, rec)
-        if self.prefetch is not None:
-            self.prefetch.finish_token(step_idx)
-        verified, self.last_logits = _logits_argmax(cfg)(self.params, x)
-        verified = verified.reshape(b, s_w)
-        c = accept_prefix(tokens, verified)
-        if max_commit is not None:
-            c = jnp.minimum(c, jnp.asarray(max_commit, jnp.int32))
-        if lockstep:
-            c = jnp.full_like(c, jnp.min(c))
-        for li in range(cfg.num_layers):
-            cache_list[li] = select_commit(spec_caches[li], c, s_w)
-        rec.spec_len = s_w
-        rec.committed = int(jnp.sum(c))
-        return verified, c, cache_list, pos + c
+        with span("decode_step", step=step_idx, rows=b * s_w,
+                  rids=joined(self.slots.request_context)):
+            if self.faults is not None:
+                self.faults.apply(step_idx, self.sched.state, self.slots)
+            x = _embed_token(self.params, tokens.reshape(-1))
+            pos_rows = (pos[:, None]
+                        + jnp.arange(s_w, dtype=pos.dtype)).reshape(-1)
+            pending: Dict[int, np.ndarray] = dict(preds)
+            if self.prefetch is not None and pending:
+                self.prefetch.enqueue(step_idx, 0, pending,
+                                      skip=self._resident_skip())
+            spec_caches: Dict[int, dict] = {}
+            moe_i = -1
+            for li, kinds in enumerate(cfg.layer_kinds()):
+                lp = self._layer_params[li]
+                # each wave row verifies against its own copy of the
+                # request's cache (seeded with the earlier rows' K/V
+                # inside the spec step); the commit below SELECTS the
+                # accepted row, so nothing is written back until
+                # acceptance
+                repl = jax.tree.map(lambda a: jnp.repeat(a, s_w, axis=0),
+                                    cache_list[li])
+                if kinds[1] != MOE_FF:
+                    x, spec_caches[li] = _spec_block_step(cfg, kinds, s_w)(
+                        lp, x, repl, pos_rows)
+                    continue
+                moe_i += 1
+                x, spec_caches[li], h, topk_idx, topk_gate = \
+                    _spec_mixer_router_step(cfg, kinds, s_w)(
+                        lp, x, repl, pos_rows)
+                with span("router_sync", layer=li):
+                    true = np.asarray(topk_idx)
+                x = self._moe_bookkeeping(step_idx, li, moe_i, pending,
+                                          true, h, topk_gate, x, rec)
+            if self.prefetch is not None:
+                self.prefetch.finish_token(step_idx)
+            verified, self.last_logits = _logits_argmax(cfg)(self.params, x)
+            verified = verified.reshape(b, s_w)
+            c = accept_prefix(tokens, verified)
+            if max_commit is not None:
+                c = jnp.minimum(c, jnp.asarray(max_commit, jnp.int32))
+            if lockstep:
+                c = jnp.full_like(c, jnp.min(c))
+            for li in range(cfg.num_layers):
+                cache_list[li] = select_commit(spec_caches[li], c, s_w)
+            rec.spec_len = s_w
+            rec.committed = int(jnp.sum(c))
+            return verified, c, cache_list, pos + c
 
     def _resident_skip(self):
         """Prefetch skip predicate under residency: an expert that is
@@ -712,49 +724,50 @@ class ODMoEEngine:
         production and the retired decode paths: on-the-fly predictors,
         serve + compute, trace recording and the cacheless eviction
         rule (or, under residency, the opportunistic release)."""
-        b = true.shape[0]
-        # on-the-fly predictors key off the router input
-        if self.fly is not None:
-            for tgt, p in self.fly.predict_from(li, h).items():
-                pending[tgt] = p
-        if self.freq is not None:
-            pending[li] = self.freq.predict(li, b)
-        if self.rand is not None:
-            pending[li] = self.rand.predict(li, b)
-        if self.prefetch is not None and pending:
-            # on-the-fly predictors only just produced this layer's (and
-            # lookahead) predictions; queue whatever is new in-window
-            self.prefetch.enqueue(step_idx, li, pending,
-                                  skip=self._resident_skip())
-        pred = pending.get(li)
-        lr, y = self._serve_and_compute(
-            step_idx, li, moe_i, pred, true, h, np.asarray(topk_gate))
-        rec.layers.append(lr)
-        if self.freq is not None:
-            self.freq.observe(li, true)
-        if self.gate_stats is not None:
-            # realized routing feeds the placement optimizer (recording
-            # only — scheduling for THIS run is untouched)
-            self.gate_stats.observe(moe_i, true, np.asarray(topk_gate))
-        if self.residency is not None:
-            # realized routing feeds the gate-statistics policy
-            self.slots.observe_gates(li, true, np.asarray(topk_gate))
-        x = x + y[:, None].astype(x.dtype)
-        # prompt eviction — cacheless rule.  Every worker that took a
-        # load this layer (predicted or reload, group or spill) drops
-        # its experts, so a mispredicted never-used resident cannot
-        # linger to fake a later hit.  Under opportunistic residency the
-        # drop becomes a *release*: residents keep their free slots and
-        # a later load of the same expert re-hits instead of reloading.
-        used = set(lr.touched)
-        used.update(w for _, w in lr.assignments)
-        used.update(self.sched.workers_of_group(lr.group))
-        for w in sorted(used):
+        with span("serve", layer=li, experts=int(np.unique(true).size)):
+            b = true.shape[0]
+            # on-the-fly predictors key off the router input
+            if self.fly is not None:
+                for tgt, p in self.fly.predict_from(li, h).items():
+                    pending[tgt] = p
+            if self.freq is not None:
+                pending[li] = self.freq.predict(li, b)
+            if self.rand is not None:
+                pending[li] = self.rand.predict(li, b)
+            if self.prefetch is not None and pending:
+                # on-the-fly predictors only just produced this layer's (and
+                # lookahead) predictions; queue whatever is new in-window
+                self.prefetch.enqueue(step_idx, li, pending,
+                                      skip=self._resident_skip())
+            pred = pending.get(li)
+            lr, y = self._serve_and_compute(
+                step_idx, li, moe_i, pred, true, h, np.asarray(topk_gate))
+            rec.layers.append(lr)
+            if self.freq is not None:
+                self.freq.observe(li, true)
+            if self.gate_stats is not None:
+                # realized routing feeds the placement optimizer (recording
+                # only — scheduling for THIS run is untouched)
+                self.gate_stats.observe(moe_i, true, np.asarray(topk_gate))
             if self.residency is not None:
-                self.slots.release(w)
-            else:
-                self.slots.evict(w)
-        return x
+                # realized routing feeds the gate-statistics policy
+                self.slots.observe_gates(li, true, np.asarray(topk_gate))
+            x = x + y[:, None].astype(x.dtype)
+            # prompt eviction — cacheless rule.  Every worker that took a
+            # load this layer (predicted or reload, group or spill) drops
+            # its experts, so a mispredicted never-used resident cannot
+            # linger to fake a later hit.  Under opportunistic residency the
+            # drop becomes a *release*: residents keep their free slots and
+            # a later load of the same expert re-hits instead of reloading.
+            used = set(lr.touched)
+            used.update(w for _, w in lr.assignments)
+            used.update(self.sched.workers_of_group(lr.group))
+            for w in sorted(used):
+                if self.residency is not None:
+                    self.slots.release(w)
+                else:
+                    self.slots.evict(w)
+            return x
 
     # ------------------------------------------- retired loop baseline
     def _decode_batch_loop(self, token, cache_list, pos, preds, step_idx,
@@ -978,18 +991,19 @@ class ODMoEEngine:
         identical round-trip worker slots hold) instead of slot
         contents, so the grouped-FFN call produces bit-identical
         contributions and the (B, k, d) accumulation stays order-free."""
-        experts = sorted(experts)
-        shards = [self.store.unpack_shard(layer, e) for e in experts]
-        stacked = {name: jnp.stack([s[name] for s in shards])
-                   for name in EXPERT_WEIGHT_NAMES}
-        eid = np.asarray(experts)
-        match = true[..., None] == eid
-        slot_map = np.where(match.any(-1), match.argmax(-1),
-                            -1).astype(np.int32)
-        wc = grouped_topk_contrib(h, stacked["w_gate"], stacked["w_up"],
-                                  stacked["w_down"], jnp.asarray(slot_map),
-                                  jnp.asarray(gates))
-        return wc if contrib is None else contrib + wc
+        with span("wave", layer=layer, experts=len(experts)):
+            experts = sorted(experts)
+            shards = [self.store.unpack_shard(layer, e) for e in experts]
+            stacked = {name: jnp.stack([s[name] for s in shards])
+                       for name in EXPERT_WEIGHT_NAMES}
+            eid = np.asarray(experts)
+            match = true[..., None] == eid
+            slot_map = np.where(match.any(-1), match.argmax(-1),
+                                -1).astype(np.int32)
+            wc = grouped_topk_contrib(h, stacked["w_gate"], stacked["w_up"],
+                                      stacked["w_down"], jnp.asarray(slot_map),
+                                      jnp.asarray(gates))
+            return wc if contrib is None else contrib + wc
 
     def _compute_wave(self, layer, h, true, gates, wave: Dict[int, int],
                       contrib):
@@ -999,33 +1013,34 @@ class ODMoEEngine:
         stacked axis, and add the gate-weighted contributions into the
         ``(B, k, d)`` accumulator (masked pairs contribute exact
         zeros, so cross-wave accumulation is order-free)."""
-        if self.packed_slots:
-            # packed-resident slots: one fused in-kernel-dequant grouped
-            # call per resident scheme group.  Pairs routed to another
-            # group's experts are masked to exact zeros, so the
-            # per-scheme split is just more wave partitioning — the
-            # accumulation stays order-free and bit-identical.
-            _, groups = self.slots.gather_stack_packed(layer, wave)
-            wc = None
-            for scheme, eids, parts in groups:
-                eid = np.asarray(eids)
-                match = true[..., None] == eid
-                slot_map = np.where(match.any(-1), match.argmax(-1),
-                                    -1).astype(np.int32)
-                gc = grouped_topk_contrib_packed(
-                    h, parts, jnp.asarray(slot_map), jnp.asarray(gates),
-                    scheme=scheme)
-                wc = gc if wc is None else wc + gc
+        with span("wave", layer=layer, experts=len(wave)):
+            if self.packed_slots:
+                # packed-resident slots: one fused in-kernel-dequant grouped
+                # call per resident scheme group.  Pairs routed to another
+                # group's experts are masked to exact zeros, so the
+                # per-scheme split is just more wave partitioning — the
+                # accumulation stays order-free and bit-identical.
+                _, groups = self.slots.gather_stack_packed(layer, wave)
+                wc = None
+                for scheme, eids, parts in groups:
+                    eid = np.asarray(eids)
+                    match = true[..., None] == eid
+                    slot_map = np.where(match.any(-1), match.argmax(-1),
+                                        -1).astype(np.int32)
+                    gc = grouped_topk_contrib_packed(
+                        h, parts, jnp.asarray(slot_map), jnp.asarray(gates),
+                        scheme=scheme)
+                    wc = gc if wc is None else wc + gc
+                return wc if contrib is None else contrib + wc
+            experts, stacked = self.slots.gather_stack(layer, wave)
+            eid = np.asarray(experts)
+            match = true[..., None] == eid               # (B, k, E_wave)
+            slot_map = np.where(match.any(-1), match.argmax(-1),
+                                -1).astype(np.int32)
+            wc = grouped_topk_contrib(h, stacked["w_gate"], stacked["w_up"],
+                                      stacked["w_down"], jnp.asarray(slot_map),
+                                      jnp.asarray(gates))
             return wc if contrib is None else contrib + wc
-        experts, stacked = self.slots.gather_stack(layer, wave)
-        eid = np.asarray(experts)
-        match = true[..., None] == eid                       # (B, k, E_wave)
-        slot_map = np.where(match.any(-1), match.argmax(-1),
-                            -1).astype(np.int32)
-        wc = grouped_topk_contrib(h, stacked["w_gate"], stacked["w_up"],
-                                  stacked["w_down"], jnp.asarray(slot_map),
-                                  jnp.asarray(gates))
-        return wc if contrib is None else contrib + wc
 
     def _compute_wave_loop(self, layer, h, true, gates,
                            wave: Dict[int, int], contrib):

@@ -29,6 +29,8 @@ from repro.models import prefill
 from repro.models.config import MOE_FF, ModelConfig
 from repro.quant import shadow_params
 
+from .spans import span
+
 
 @functools.lru_cache(maxsize=None)
 def _shadow_rollout_step(cfg: ModelConfig, S: int):
@@ -152,11 +154,13 @@ class SEPShadow:
         """Pure one-step shadow decode (one jitted dispatch): consume
         ``token`` against ``state``; return ``({layer: predicted
         (B,k)}, new_state)`` without touching the stateful shadow."""
-        logits, caches, aux = self._step(self.params, token,
-                                         state["caches"], state["pos"])
-        new = dict(state, caches=caches, pos=state["pos"] + 1,
-                   token=jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        return topk_to_layer_dict(self.cfg, aux["topk"]), new
+        with span("shadow_step", rows=int(token.shape[0])):
+            logits, caches, aux = self._step(self.params, token,
+                                             state["caches"], state["pos"])
+            new = dict(state, caches=caches, pos=state["pos"] + 1,
+                       token=jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            # the routing readback waits for the shadow step to finish
+            return topk_to_layer_dict(self.cfg, aux["topk"]), new
 
     def rollout_states(self, state: dict, token, S: int):
         """Fused ``S``-step rollout (one jitted scan dispatch — the

@@ -64,6 +64,8 @@ from repro.quant.transport import (EXPERT_WEIGHT_NAMES, PackedWeight,
                                    device_layout, resolve_policy,
                                    tileable)
 
+from .spans import span
+
 
 @dataclass
 class LoadEvent:
@@ -270,6 +272,11 @@ class WorkerSlots:
         serves — the amortization signal the serving benchmarks report."""
         self._request_context = tuple(int(r) for r in request_ids)
 
+    @property
+    def request_context(self) -> Tuple[int, ...]:
+        """The request ids load events are tagged with right now."""
+        return self._request_context
+
     # ------------------------------------------------------------- actions
     def load(self, token: int, layer: int, expert: int, worker: int,
              predicted: bool, payload: Optional[dict] = None) -> bool:
@@ -316,19 +323,22 @@ class WorkerSlots:
             self.stats["evictions"] += 1
             self.residency_stats["evicted_bytes"] += \
                 self._resident_nbytes(victim)
-        if payload is not None:
-            data = payload
-        elif self.packed_resident:
-            data = self.store.device_shard(layer, expert,
-                                           device=self.physical)
-        else:
-            data = self.store.unpack_shard(layer, expert,
-                                           device=self.physical)
+        nbytes = self.store.packed_bytes(layer, expert)
+        # the shipping path's host->device copy (hits move nothing)
+        with span("expert_load", layer=int(layer), expert=int(expert),
+                  nbytes=nbytes, predicted=bool(predicted)):
+            if payload is not None:
+                data = payload
+            elif self.packed_resident:
+                data = self.store.device_shard(layer, expert,
+                                               device=self.physical)
+            else:
+                data = self.store.unpack_shard(layer, expert,
+                                               device=self.physical)
         self._slot_data[worker][key] = data
         self._occupied[worker].append(key)
         self.stats["loads"] += 1
         self.stats["predicted_loads" if predicted else "reloads"] += 1
-        nbytes = self.store.packed_bytes(layer, expert)
         self.bytes_moved += nbytes
         if self.residency is not None:
             self.residency.note(key)

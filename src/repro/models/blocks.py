@@ -101,15 +101,20 @@ def block_decode(cfg: ModelConfig, params, kinds, x, cache, pos, *,
                  memory: Optional[dict] = None, moe_method: str = "dense"):
     """One-token block.  x: (B,1,d).  Returns (x, new_cache, aux)."""
     mixer = kinds[0]
-    h = apply_norm(cfg, x, params["norm1"])
-    if mixer == ATTN:
-        out, cache = attn_lib.attn_decode(cfg, params["mixer"], h, cache, pos)
-        x = x + out
-        if memory is not None and "cross" in params:
-            hc = apply_norm(cfg, x, params["norm_cross"])
-            x = x + attn_lib.cross_attn(cfg, params["cross"], hc, memory)
-    else:
-        out, cache = mamba_lib.mamba_decode(cfg, params["mixer"], h, cache)
-        x = x + out
+    # named scopes label the device ops in a profiler trace (no arithmetic)
+    with jax.named_scope("mixer"):
+        h = apply_norm(cfg, x, params["norm1"])
+        if mixer == ATTN:
+            with jax.named_scope("attention"):
+                out, cache = attn_lib.attn_decode(cfg, params["mixer"], h,
+                                                  cache, pos)
+            x = x + out
+            if memory is not None and "cross" in params:
+                hc = apply_norm(cfg, x, params["norm_cross"])
+                x = x + attn_lib.cross_attn(cfg, params["cross"], hc, memory)
+        else:
+            out, cache = mamba_lib.mamba_decode(cfg, params["mixer"], h,
+                                                cache)
+            x = x + out
     x, aux = _apply_ff(cfg, params, kinds, x, moe_method)
     return x, cache, aux

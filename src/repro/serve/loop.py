@@ -83,6 +83,7 @@ from repro.core import (AlignmentPolicy, DecodeClock, LayerRecord,
                         slice_cache_list, slice_shadow_state,
                         simulate_prefill_odmoe, wave_preds)
 from repro.core.predictor import recall_counts
+from repro.core.spans import install_gc_span, span
 from repro.core.timing import HardwareProfile
 from .composer import BatchComposer
 from .kvpool import KVPool, PoolExhausted
@@ -251,26 +252,27 @@ class ServingLoop:
         time); its first token is emitted here.  Paged serving adopts
         the prefilled KV straight into pool pages (the caller verified
         they fit)."""
-        eng = self.engine
-        arrival_wait_end = clock.now
-        t_pre = simulate_prefill_odmoe(
-            eng.cfg, self.profile, len(req.prompt),
-            n_workers=eng.sched.n_workers)
-        clock.charge_prefill(t_pre)
-        batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
-        token, cache_list, pos = eng.prefill_request(
-            batch, cache_len, kv_pool=self.kv_pool,
-            rid=req.rid if self.kv_pool is not None else None)
-        state = RequestState(request=req, token=token,
-                             cache_list=cache_list, pos=pos,
-                             admit_s=arrival_wait_end,
-                             first_token_s=clock.now)
-        state.admit_seq = self._admit_seq
-        self._admit_seq += 1
-        state.generated.append(int(token[0]))
-        if eng.shadow is not None:
-            state.shadow_state = eng.shadow.prefill_state(batch, cache_len)
-        return state
+        with span("admit", rid=req.rid, prompt_len=len(req.prompt)):
+            eng = self.engine
+            arrival_wait_end = clock.now
+            t_pre = simulate_prefill_odmoe(
+                eng.cfg, self.profile, len(req.prompt),
+                n_workers=eng.sched.n_workers)
+            clock.charge_prefill(t_pre)
+            batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
+            token, cache_list, pos = eng.prefill_request(
+                batch, cache_len, kv_pool=self.kv_pool,
+                rid=req.rid if self.kv_pool is not None else None)
+            state = RequestState(request=req, token=token,
+                                 cache_list=cache_list, pos=pos,
+                                 admit_s=arrival_wait_end,
+                                 first_token_s=clock.now)
+            state.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            state.generated.append(int(token[0]))
+            if eng.shadow is not None:
+                state.shadow_state = eng.shadow.prefill_state(batch, cache_len)
+            return state
 
     def _pool_fits_prompt(self, req: Request) -> bool:
         pool = self.kv_pool
@@ -452,38 +454,39 @@ class ServingLoop:
         need = [s for s in runnable if s.pending is None]
         if not need:
             return
-        aligned, flags = [], []
-        for state in need:
-            n = len(state.generated)      # request-local iteration index
-            at = self.policy.align_token_at(n)
-            ak = self.policy.align_kv_at(n)
-            sh = state.shadow_state
-            if ak:
-                sh = eng.shadow.align_kv_state(
-                    sh, {"caches": eng._stack(state.cache_list),
-                         "pos": state.pos})
-            # the composed ``token`` field carries each request's chosen
-            # shadow input (main token when aligning, else the shadow's)
-            aligned.append(dict(sh, token=state.token if at
-                                else sh["token"]))
-            flags.append((at, ak))
-        composed = concat_shadow_states(aligned)
-        preds_steps, snapshots = [], []
-        st, tok = composed, composed["token"]
-        for _ in range(self.speculate):
-            preds, st = eng.shadow.step_state(st, tok)
-            preds_steps.append(preds)
-            snapshots.append(st)
-            tok = st["token"]             # the shadow's greedy draft
-        for i, (state, (at, ak)) in enumerate(zip(need, flags)):
-            p_i = [{li: p[i:i + 1] for li, p in ps.items()}
-                   for ps in preds_steps]
-            s_i = [slice_shadow_state(s, i) for s in snapshots]
-            drafts = (jnp.stack([s["token"][i:i + 1]
-                                 for s in snapshots[:-1]], axis=1)
-                      if self.speculate > 1
-                      else jnp.zeros((1, 0), jnp.int32))
-            state.pending = (p_i, s_i, at, ak, drafts)
+        with span("peek", rows=len(need)):
+            aligned, flags = [], []
+            for state in need:
+                n = len(state.generated)      # request-local iteration index
+                at = self.policy.align_token_at(n)
+                ak = self.policy.align_kv_at(n)
+                sh = state.shadow_state
+                if ak:
+                    sh = eng.shadow.align_kv_state(
+                        sh, {"caches": eng._stack(state.cache_list),
+                             "pos": state.pos})
+                # the composed ``token`` field carries each request's chosen
+                # shadow input (main token when aligning, else the shadow's)
+                aligned.append(dict(sh, token=state.token if at
+                                    else sh["token"]))
+                flags.append((at, ak))
+            composed = concat_shadow_states(aligned)
+            preds_steps, snapshots = [], []
+            st, tok = composed, composed["token"]
+            for _ in range(self.speculate):
+                preds, st = eng.shadow.step_state(st, tok)
+                preds_steps.append(preds)
+                snapshots.append(st)
+                tok = st["token"]             # the shadow's greedy draft
+            for i, (state, (at, ak)) in enumerate(zip(need, flags)):
+                p_i = [{li: p[i:i + 1] for li, p in ps.items()}
+                       for ps in preds_steps]
+                s_i = [slice_shadow_state(s, i) for s in snapshots]
+                drafts = (jnp.stack([s["token"][i:i + 1]
+                                     for s in snapshots[:-1]], axis=1)
+                          if self.speculate > 1
+                          else jnp.zeros((1, 0), jnp.int32))
+                state.pending = (p_i, s_i, at, ak, drafts)
 
     # --------------------------------------------------------------- run
     def start(self, requests: Sequence[Request], *,
@@ -497,6 +500,7 @@ class ServingLoop:
         ``worker_free`` fleet timeline) and a cluster-wide
         ``cache_len``."""
         eng = self.engine
+        install_gc_span()
         requests = list(requests)
         if cache_len is None:
             if not requests:
@@ -547,65 +551,66 @@ class ServingLoop:
         left to do."""
         if not self.has_work():
             return False
-        queue, clock = self._queue, self._clock
-        deferred, cache_len = self._deferred, self._cache_len
-        progressed = False
-        if self.kv_pool is not None:
-            progressed |= self._resume_preempted(queue, clock)
-            while deferred and self._admission_fits(deferred.peek()):
-                self._admit_or_retire(deferred.pop(), cache_len,
-                                      clock, queue)
+        with span("tick", step=self._step):
+            queue, clock = self._queue, self._clock
+            deferred, cache_len = self._deferred, self._cache_len
+            progressed = False
+            if self.kv_pool is not None:
+                progressed |= self._resume_preempted(queue, clock)
+                while deferred and self._admission_fits(deferred.peek()):
+                    self._admit_or_retire(deferred.pop(), cache_len,
+                                          clock, queue)
+                    progressed = True
+            arrived = queue.pop_arrived(clock.now)
+            if self.admit_policy == "priority":
+                # weightiest tenant first; FIFO within a weight class
+                arrived.sort(key=lambda r: (-r.weight, r.arrival_s,
+                                            r.rid))
+            for req in arrived:
+                # budget-aware admission drains the deferred backlog in
+                # the admission policy's order — strictly FIFO by
+                # default: while an older request waits for pages,
+                # younger arrivals queue behind it (mirrors the resume
+                # path), otherwise a stream of small requests could
+                # starve a large one.  Under "priority" the backlog is
+                # weight-ordered instead, so interactive arrivals jump
+                # deferred batch traffic.
+                if deferred or not self._admission_fits(req):
+                    self.kv_pool.stats.deferred_admissions += 1
+                    deferred.push(req)
+                    continue
+                self._admit_or_retire(req, cache_len, clock, queue)
                 progressed = True
-        arrived = queue.pop_arrived(clock.now)
-        if self.admit_policy == "priority":
-            # weightiest tenant first; FIFO within a weight class
-            arrived.sort(key=lambda r: (-r.weight, r.arrival_s,
-                                        r.rid))
-        for req in arrived:
-            # budget-aware admission drains the deferred backlog in
-            # the admission policy's order — strictly FIFO by
-            # default: while an older request waits for pages,
-            # younger arrivals queue behind it (mirrors the resume
-            # path), otherwise a stream of small requests could
-            # starve a large one.  Under "priority" the backlog is
-            # weight-ordered instead, so interactive arrivals jump
-            # deferred batch traffic.
-            if deferred or not self._admission_fits(req):
-                self.kv_pool.stats.deferred_admissions += 1
-                deferred.push(req)
-                continue
-            self._admit_or_retire(req, cache_len, clock, queue)
-            progressed = True
-        if self.prefill_chunk:
-            progressed |= self._advance_prefills(queue, clock,
-                                                 cache_len)
-        runnable = queue.runnable()
-        if not runnable:
-            nxt = queue.next_arrival_s()
-            if nxt is not None:
-                clock.advance_to(nxt)        # idle until the next arrival
-                return True
-            if queue.all_done and not deferred:
-                return False
-            if progressed:
-                return True                  # retires freed pages; retry
-            raise RuntimeError(
-                "KV pool deadlock: nothing runnable, resumable or "
-                "admittable (pool smaller than one request window?)")
-        self._ensure_peeks(runnable)
-        batch = self.composer.compose(runnable)
-        if self.kv_pool is not None:
-            batch = self._ensure_batch_pages(batch, queue, clock)
-            if not batch:
-                return True                  # preemptions freed pages
-        self._decode_composed(batch, clock, self._trace, self._steps,
-                              self._step, queue.state_counts())
-        for state in list(batch):
-            if state.done:
-                state.finish_s = clock.now
-                self._retire(state, queue)
-        self._step += 1
-        return True
+            if self.prefill_chunk:
+                progressed |= self._advance_prefills(queue, clock,
+                                                     cache_len)
+            runnable = queue.runnable()
+            if not runnable:
+                nxt = queue.next_arrival_s()
+                if nxt is not None:
+                    clock.advance_to(nxt)        # idle until the next arrival
+                    return True
+                if queue.all_done and not deferred:
+                    return False
+                if progressed:
+                    return True                  # retires freed pages; retry
+                raise RuntimeError(
+                    "KV pool deadlock: nothing runnable, resumable or "
+                    "admittable (pool smaller than one request window?)")
+            self._ensure_peeks(runnable)
+            batch = self.composer.compose(runnable)
+            if self.kv_pool is not None:
+                batch = self._ensure_batch_pages(batch, queue, clock)
+                if not batch:
+                    return True                  # preemptions freed pages
+            self._decode_composed(batch, clock, self._trace, self._steps,
+                                  self._step, queue.state_counts())
+            for state in list(batch):
+                if state.done:
+                    state.finish_s = clock.now
+                    self._retire(state, queue)
+            self._step += 1
+            return True
 
     def run(self, requests: Sequence[Request]) -> ServeResult:
         eng = self.engine
@@ -666,7 +671,8 @@ class ServingLoop:
         eng = self.engine
         S = self.speculate
         pos = jnp.concatenate([s.pos for s in batch])
-        caches = concat_cache_lists([s.cache_list for s in batch])
+        with span("kv_gather", rows=len(batch)):
+            caches = concat_cache_lists([s.cache_list for s in batch])
         preds: Dict[int, np.ndarray] = {}
         at = ak = False
         if eng.shadow is not None:
@@ -696,7 +702,8 @@ class ServingLoop:
         verified, commits, caches, pos = eng.decode_batch_spec(
             tokens, caches, pos, preds, step, rec, max_commit=budget)
         eng.slots.set_request_context(())
-        duration, stall = clock.step(rec)
+        with span("model_clock"):
+            duration, stall = clock.step(rec)
         trace.records.append(rec)
         steps.append(StepRecord(step=step,
                                 request_ids=[s.rid for s in batch],
@@ -708,30 +715,33 @@ class ServingLoop:
                                                else -1),
                                 queue_counts=queue_counts))
         sl = rec.spec_len                     # wave rows per request
-        for i, state in enumerate(batch):
-            ci = int(commits[i])
-            state.token = verified[i, ci - 1:ci]
-            state.cache_list = slice_cache_list(caches, i)
-            state.pos = pos[i:i + 1]
-            state.generated.extend(int(t) for t in verified[i, :ci])
-            if state.pending is not None:
-                # rollback to the snapshot that consumed exactly the
-                # accepted tokens — the peek's rejected drafts die here
-                state.shadow_state = state.pending[1][ci - 1]
-            state.pending = None
-            state.spec_waves += 1
-            state.spec_committed += ci
-            lo = i * sl                       # this request's wave rows;
-            #                                   only accepted ones count
-            state.last_experts = frozenset(
-                (lr.layer, int(e)) for lr in rec.layers
-                for e in lr.true[lo:lo + ci].reshape(-1))
-            sliced = self._slice_record(rec, lo, lo + ci)
-            sliced.index = len(state.generated) - ci  # wave-start n
-            state.trace.records.append(sliced)
-            if eng.keep_logits:
-                state.trace.logits.extend(
-                    eng.last_logits[lo + j:lo + j + 1] for j in range(ci))
+        # the token readbacks wait for the step's last device work
+        with span("commit", rows=len(batch)):
+            for i, state in enumerate(batch):
+                ci = int(commits[i])
+                state.token = verified[i, ci - 1:ci]
+                with span("kv_scatter"):
+                    state.cache_list = slice_cache_list(caches, i)
+                state.pos = pos[i:i + 1]
+                state.generated.extend(int(t) for t in verified[i, :ci])
+                if state.pending is not None:
+                    # rollback to the snapshot that consumed exactly the
+                    # accepted tokens — the peek's rejected drafts die here
+                    state.shadow_state = state.pending[1][ci - 1]
+                state.pending = None
+                state.spec_waves += 1
+                state.spec_committed += ci
+                lo = i * sl                       # this request's wave rows;
+                #                                   only accepted ones count
+                state.last_experts = frozenset(
+                    (lr.layer, int(e)) for lr in rec.layers
+                    for e in lr.true[lo:lo + ci].reshape(-1))
+                sliced = self._slice_record(rec, lo, lo + ci)
+                sliced.index = len(state.generated) - ci  # wave-start n
+                state.trace.records.append(sliced)
+                if eng.keep_logits:
+                    state.trace.logits.extend(
+                        eng.last_logits[lo + j:lo + j + 1] for j in range(ci))
 
     @staticmethod
     def _slice_record(rec: TokenRecord, lo: int, hi: int) -> TokenRecord:
