@@ -56,8 +56,7 @@ from repro.models.layers import apply_norm, embed
 from repro.models.moe import route
 from repro.models.transformer import layer_params, logits_from_hidden
 from repro.quant.quantize import shadow_nbytes
-from repro.quant.transport import (EXPERT_WEIGHT_NAMES, resolve_policy,
-                                   transport_params)
+from repro.quant.transport import resolve_policy, transport_params
 
 from .align import AlignmentPolicy
 from .predictor import (FrequencyPredictor, GateExtrapolator, RandomPredictor,
@@ -68,7 +67,7 @@ from .specdecode import (_spec_block_step, _spec_mixer_router_step,
 from .prefetch import PrefetchExecutor, make_executor, resolve_residency
 from .schedule import GroupSchedule
 from .spans import joined, span
-from .store import ExpertStore, WorkerSlots
+from .store import ExpertStore, WorkerSlots, stack_shards
 
 
 @dataclass
@@ -993,9 +992,8 @@ class ODMoEEngine:
         contributions and the (B, k, d) accumulation stays order-free."""
         with span("wave", layer=layer, experts=len(experts)):
             experts = sorted(experts)
-            shards = [self.store.unpack_shard(layer, e) for e in experts]
-            stacked = {name: jnp.stack([s[name] for s in shards])
-                       for name in EXPERT_WEIGHT_NAMES}
+            stacked = stack_shards(
+                [self.store.unpack_shard(layer, e) for e in experts])
             eid = np.asarray(experts)
             match = true[..., None] == eid
             slot_map = np.where(match.any(-1), match.argmax(-1),

@@ -67,6 +67,18 @@ from repro.quant.transport import (EXPERT_WEIGHT_NAMES, PackedWeight,
 from .spans import span
 
 
+@jax.jit
+def stack_shards(shards: Sequence[Dict[str, jax.Array]]
+                 ) -> Dict[str, jax.Array]:
+    """Stack experts' full-width weights along a new leading axis, in
+    one program: ``{w_gate/w_up: (E, d, f), w_down: (E, f, d)}``.  Eager
+    ``jnp.stack`` dispatches one op per expert and weight, each with its
+    own output buffer, which costs the host more than the copy costs the
+    device."""
+    return {name: jnp.stack([s[name] for s in shards])
+            for name in EXPERT_WEIGHT_NAMES}
+
+
 @dataclass
 class LoadEvent:
     token: int              # decoding iteration (serving: global step index)
@@ -448,10 +460,8 @@ class WorkerSlots:
         resident* on its assigned worker — the grouped hot path still
         consumes genuine slot contents, never the host store."""
         experts = sorted(wave)
-        shards = [self.slot(wave[e], layer, e) for e in experts]
-        stacked = {name: jnp.stack([s[name] for s in shards])
-                   for name in EXPERT_WEIGHT_NAMES}
-        return experts, stacked
+        return experts, stack_shards(
+            [self.slot(wave[e], layer, e) for e in experts])
 
     def gather_stack_packed(self, layer: int, wave: Dict[int, int]):
         """Packed-resident sibling of :meth:`gather_stack`: stack each
