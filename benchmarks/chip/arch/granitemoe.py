@@ -1,8 +1,32 @@
-"""Plain float32 forward of an all-attention, all-MoE decoder (Granite-MoE).
+"""Granite-MoE (``"model_type": "granitemoe"``): an all-attention, all-MoE
+decoder, every layer grouped-query attention with rotary embeddings and
+a routed SwiGLU expert FFN.
 
-Written from the architecture, not from the program: it imports nothing
-of ``repro``.  One jitted program per layer, run layer by layer over the
-whole sequence, so it fits beside the weights once the engine is freed.
+The harness finds this file by the configuration file's ``model_type``
+(``chipbench/spec.py``).  It gives what the benchmark needs to know of
+the architecture:
+
+* ``model_config(raw)``: the published ``config.json`` keys -> the
+  program's ``ModelConfig``;
+* ``make_params(cfg, seed)``: seeded weights in the layout the program
+  takes;
+* ``Arch.from_config(raw)`` and ``forward(...)``: the float32 reference
+  and the float8 control;
+* ``prompt_flops(cfg, n)`` and ``decode_flops(cfg, context)``: the
+  model's operations per prompt and per decoded token.
+
+Weights.  Made on the device in one jitted call, in the configuration's
+dtype, in the layout the program's serving entry points take
+(``{"embed", "final_norm", "layers": (stacked block,)}``).  Scales follow
+the usual fan-in rule: matrices ~ N(0, 1/fan_in), the embedding ~ N(0,
+1), norm scales 1.  The benchmark makes the weights itself, so the
+reference and the program read the same arrays and the program makes
+nothing the reference consumes.
+
+Reference.  Written from the architecture, not from the program: it
+imports nothing of ``repro``.  One jitted program per layer, run layer
+by layer over the whole sequence, so it fits beside the weights once
+the engine is freed.
 
 Per layer: RMSNorm, grouped-query attention with rotary embeddings
 (rotate-half, both halves of each head), causal softmax, residual;
@@ -22,20 +46,120 @@ k-th best router logit.
 ``precision="highest"``.  ``mode="fp8"`` (the control): the same
 forward with every matrix round-tripped through float8 e4m3 with one
 scale per output channel, computed in bfloat16 at default precision.
+
+Operations.  2 x the active parameters that multiply a token
+(projections, router, the top-k experts, the output head), plus the
+attention scores and weighted values over its context.  (The per-token
+arithmetic follows ``benchmarks/roofline.py::fwd_flops_per_token``.)
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.flops import expert_flops
+from chipbench.weights import seed_key
+from chipbench.yardstick import RefOut
+
 NEG_INF = -1e30
 
 
+# ------------------------------------------------------------ configuration
+def model_config(raw: dict):
+    from repro.models.config import ModelConfig
+    if raw.get("hidden_act", "silu") != "silu":
+        raise ValueError("the program's experts are SwiGLU (silu) only")
+    prog = raw.get("program", {})
+    return ModelConfig(
+        name=raw["name"], family="moe",
+        num_layers=int(raw["num_hidden_layers"]),
+        d_model=int(raw["hidden_size"]),
+        num_heads=int(raw["num_attention_heads"]),
+        num_kv_heads=int(raw["num_key_value_heads"]),
+        d_ff=int(raw["intermediate_size"]),
+        vocab_size=int(raw["vocab_size"]),
+        num_experts=int(raw["num_local_experts"]),
+        top_k=int(raw["num_experts_per_tok"]),
+        d_expert=int(raw["intermediate_size"]),
+        padded_experts=int(prog.get("padded_experts", 0)),
+        rope_theta=float(raw["rope_theta"]),
+        norm_eps=float(raw["rms_norm_eps"]),
+        tie_embeddings=bool(raw["tie_word_embeddings"]),
+        dtype=str(raw["torch_dtype"]),
+        source=raw["source"])
+
+
+# ------------------------------------------------------------------ weights
+def shapes(cfg) -> dict:
+    """Leaf name -> (shape, fan_in or None for ones, or 'embed')."""
+    L, d, hd = cfg.num_layers, cfg.d_model, cfg.resolved_head_dim
+    h, kv, f = cfg.num_heads, cfg.num_kv_heads, cfg.d_expert_resolved
+    e, ep, v = cfg.num_experts, cfg.num_experts_padded, cfg.vocab_size
+    out = {
+        "embed/table": ((v, d), "embed"),
+        "final_norm/scale": ((d,), None),
+        "norm1/scale": ((L, d), None),
+        "mixer/wq": ((L, d, h * hd), d),
+        "mixer/wk": ((L, d, kv * hd), d),
+        "mixer/wv": ((L, d, kv * hd), d),
+        "mixer/wo": ((L, h * hd, d), h * hd),
+        "norm2/scale": ((L, d), None),
+        "ff/router": ((L, d, e), d),
+        "ff/w_gate": ((L, ep, d, f), d),
+        "ff/w_up": ((L, ep, d, f), d),
+        "ff/w_down": ((L, ep, f, d), f),
+    }
+    if not cfg.tie_embeddings:
+        out["head/w"] = ((d, v), d)
+    return out
+
+
+def _check(cfg) -> None:
+    kinds = cfg.layer_kinds()
+    if cfg.pattern()[1] != cfg.num_layers or kinds[0] != ("attn", "moe"):
+        raise ValueError("weights are made for all-attention, all-MoE "
+                         "decoders")
+    if cfg.qkv_bias or cfg.norm_type != "rmsnorm":
+        raise ValueError("weights are made without qkv bias, with RMSNorm")
+
+
+@functools.lru_cache(maxsize=None)
+def _maker(cfg):
+    _check(cfg)
+    dt = jnp.dtype(cfg.dtype)
+    leaves = shapes(cfg)
+
+    def make(key):
+        flat = {}
+        keys = jax.random.split(key, len(leaves))
+        for k, (name, (shape, fan)) in zip(keys, sorted(leaves.items())):
+            if fan is None:
+                flat[name] = jnp.ones(shape, dt)
+            else:
+                scale = 1.0 if fan == "embed" else fan ** -0.5
+                flat[name] = jax.random.normal(k, shape, dt) * jnp.asarray(
+                    scale, dt)
+        tree: dict = {}
+        for name, arr in flat.items():
+            group, leaf = name.split("/")
+            tree.setdefault(group, {})[leaf] = arr
+        layer = {g: tree.pop(g) for g in ("norm1", "mixer", "norm2", "ff")}
+        tree["layers"] = (layer,)
+        return tree
+    return jax.jit(make)
+
+
+def make_params(cfg, seed: int) -> dict:
+    params = _maker(cfg)(seed_key(seed))
+    jax.block_until_ready(params)
+    return params
+
+
+# ---------------------------------------------------------------- reference
 @dataclass(frozen=True)
 class Arch:
     """The sizes the reference needs, read from a configuration file."""
@@ -182,13 +306,6 @@ def pad_len(n: int) -> int:
     return b
 
 
-@dataclass
-class RefOut:
-    logits: np.ndarray               # (P, V) at the judged positions
-    routing: Dict[int, np.ndarray]   # layer -> (P, k) experts used there
-    router: Dict[int, np.ndarray]    # layer -> (P, E) router logits there
-
-
 def forward(arch: Arch, params, tokens, out_pos, forced=None,
             mode: str = "f32") -> RefOut:
     """Forward of ``tokens`` (T,), read at positions ``out_pos`` (P,).
@@ -219,3 +336,34 @@ def forward(arch: Arch, params, tokens, out_pos, forced=None,
     logits = np.asarray(_head_fn(arch, mode)(params, x,
                                              jnp.asarray(out_idx)))[:P]
     return RefOut(logits=logits, routing=routing, router=router)
+
+
+# --------------------------------------------------------------- operations
+def active_matmul_flops(cfg) -> int:
+    """Matmul FLOPs of one token through the model, attention scores
+    excluded: projections, router, the top-k experts and the output
+    head (2 x the active parameters that multiply the token)."""
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    h, kv = cfg.num_heads, cfg.num_kv_heads
+    per_layer = (2 * d * (h * hd + 2 * kv * hd)        # q, k, v
+                 + 2 * h * hd * d                      # o
+                 + 2 * d * cfg.num_experts             # router
+                 + cfg.top_k * expert_flops(d, cfg.d_expert_resolved))
+    return cfg.num_layers * per_layer + 2 * d * cfg.vocab_size
+
+
+def attention_flops(cfg, context: int) -> int:
+    """Scores and weighted values of one token over ``context`` keys."""
+    return cfg.num_layers * 4 * cfg.num_heads * cfg.resolved_head_dim \
+        * context
+
+
+def prompt_flops(cfg, n: int) -> int:
+    """Model FLOPs of a causal prefill of ``n`` tokens."""
+    return n * active_matmul_flops(cfg) + attention_flops(
+        cfg, n * (n + 1) // 2)
+
+
+def decode_flops(cfg, context: int) -> int:
+    """Model FLOPs of one decoded token that attends ``context`` keys."""
+    return active_matmul_flops(cfg) + attention_flops(cfg, context)

@@ -27,7 +27,6 @@ import numpy as np
 
 from . import config as config_mod
 from . import spec, stats, traffic, yardstick
-from .reference import Arch
 
 WARMUP_RID = 1 << 30
 POST_WINDOW_S = 60.0
@@ -97,6 +96,7 @@ class RunRecord:
     """What a run hands the metric readers."""
     cell: object
     cfg: object                 # ModelConfig
+    plugin: object              # the architecture plug-in (its FLOP count)
     mix: traffic.Mix
     window: tuple               # host seconds (t0, t_end)
     clients: List[Client]
@@ -394,8 +394,8 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
         spec_file=None, fault: Optional[Callable] = None,
         compile_cache: bool = True, keep_served: bool = False) -> dict:
     """One run.  Returns the result object (the harness prints it);
-    with ``keep_served`` also the judged requests, the weights and the
-    architecture, for the control."""
+    with ``keep_served`` also the judged requests, the weights, the
+    architecture plug-in and its ``Arch``, for the control."""
     cell = spec.load_cell(cell_name, spec_file)
     if require_chip:
         devs = check_devices(cell.chips)
@@ -405,7 +405,6 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
     import jax
     from . import peaks as peaks_mod
     from . import trace as trace_mod
-    from .weights import make_params
     cache = (enable_compile_cache(str(base / ".cache" / "jax"))
              if compile_cache else "off")
     counter = CompileCounter()
@@ -420,7 +419,8 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
         f"seed {seed}, {seconds} s, trace {int(traced)}; device "
         f"{dev.device_kind} x{len(devs)}; compile cache {cache}")
     t = time.perf_counter()
-    params = make_params(bench_cfg.model, seed)
+    plugin = bench_cfg.plugin
+    params = plugin.make_params(bench_cfg.model, seed)
     t_weights = time.perf_counter() - t
     t = time.perf_counter()
     h = Harness(bench_cfg, mix, params)
@@ -468,9 +468,9 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
         f"{e2e['gaps']} gaps, {e2e['requests']} requests due; "
         f"{window_compiles} compiles in the window; peak "
         f"{peak_bytes} bytes")
-    run_rec = RunRecord(cell=cell, cfg=bench_cfg.model, mix=mix,
-                        window=(t0, t_end), clients=clients, steps=steps,
-                        records=records,
+    run_rec = RunRecord(cell=cell, cfg=bench_cfg.model, plugin=plugin,
+                        mix=mix, window=(t0, t_end), clients=clients,
+                        steps=steps, records=records,
                         prefills=[n for t, n in h.prefills
                                   if t0 <= t <= t_end],
                         counters=counters, peaks=peak_table)
@@ -494,10 +494,10 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
     served = [h.served(rid) for rid in picked]
     del h, run_rec
     gc.collect()
-    arch = Arch.from_config(bench_cfg.raw)
+    arch = plugin.Arch.from_config(bench_cfg.raw)
     t = time.perf_counter()
     readings = yardstick.summarize([
-        yardstick.program_readings(arch, params, s) for s in served])
+        yardstick.program_readings(plugin, arch, params, s) for s in served])
     judged = sum(s.steps for s in served)
     correct, rows = yardstick.judge(readings, limits)
     correct = correct and judged > 0
@@ -519,5 +519,5 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, log,
                             for n, v, lim in rows}}}
     out = {"result": result, "check_lines": lines}
     if keep_served:
-        out.update(served=served, params=params, arch=arch)
+        out.update(served=served, params=params, plugin=plugin, arch=arch)
     return out

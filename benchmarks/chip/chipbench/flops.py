@@ -1,9 +1,10 @@
-"""Operations and bytes the model and its expert layer need.
+"""Operations and bytes the grouped expert GEMM needs, and the roofline.
 
 Counted from shapes and from the routing the program recorded, never
 from what an implementation happens to compute: padded experts, padded
-rows and the SEP shadow's work are not the model's work.  (The per-token
-arithmetic follows ``benchmarks/roofline.py::fwd_flops_per_token``.)
+rows and the SEP shadow's work are not the model's work.  The whole
+model's count per token is its architecture plug-in's
+(``arch/<model_type>.py``: ``prompt_flops``, ``decode_flops``).
 """
 from __future__ import annotations
 
@@ -17,36 +18,6 @@ def expert_flops(d: int, f: int) -> int:
 
 def expert_bytes(d: int, f: int, itemsize: int) -> int:
     return 3 * d * f * itemsize
-
-
-def active_matmul_flops(cfg) -> int:
-    """Matmul FLOPs of one token through the model, attention scores
-    excluded: projections, router, the top-k experts and the output
-    head (2 x the active parameters that multiply the token)."""
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    h, kv = cfg.num_heads, cfg.num_kv_heads
-    per_layer = (2 * d * (h * hd + 2 * kv * hd)        # q, k, v
-                 + 2 * h * hd * d                      # o
-                 + 2 * d * cfg.num_experts             # router
-                 + cfg.top_k * expert_flops(d, cfg.d_expert_resolved))
-    return cfg.num_layers * per_layer + 2 * d * cfg.vocab_size
-
-
-def attention_flops(cfg, context: int) -> int:
-    """Scores and weighted values of one token over ``context`` keys."""
-    return cfg.num_layers * 4 * cfg.num_heads * cfg.resolved_head_dim \
-        * context
-
-
-def prompt_flops(cfg, n: int) -> int:
-    """Model FLOPs of a causal prefill of ``n`` tokens."""
-    return n * active_matmul_flops(cfg) + attention_flops(
-        cfg, n * (n + 1) // 2)
-
-
-def decode_flops(cfg, context: int) -> int:
-    """Model FLOPs of one decoded token that attends ``context`` keys."""
-    return active_matmul_flops(cfg) + attention_flops(cfg, context)
 
 
 def least_time_s(flops: float, nbytes: float, peak_flops: float,

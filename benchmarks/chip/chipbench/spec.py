@@ -1,14 +1,20 @@
-"""Find a cell, its configuration, its traffic mix and its metrics by name.
+"""Find a cell, its configuration, its traffic mix, its architecture and
+its metrics by name.
 
 The benchmark is driven by ``BENCHMARK.json``: a new cell, configuration,
 traffic mix or per-layer metric is a new entry there plus a file of its
-own in ``configs/``, ``traffic/`` or ``metrics/``.  Nothing in this
-package has to change for one.
+own in ``configs/``, ``traffic/`` or ``metrics/``.  A configuration names
+its architecture by the published ``model_type`` key, and a new
+architecture is one new file, ``arch/<model_type>.py`` (what it gives
+is in ``arch/granitemoe.py``'s docstring).  Nothing in this package has
+to change for any of them.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
@@ -78,16 +84,39 @@ def load_json(kind: str, name: str, base: Path = BENCH_DIR) -> dict:
     return json.loads(data_file(kind, name, base).read_text())
 
 
+def _load_module(prefix: str, f: Path):
+    """Import ``f`` once per name and contents, so the jitted functions
+    and caches it keeps are shared by every caller in the process (and
+    by every copy of the file, as the tests' trees hold)."""
+    tag = hashlib.sha256(f.read_bytes()).hexdigest()[:16]
+    name = f"{prefix}{f.stem.replace('.', '_').replace('-', '_')}_{tag}"
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, f)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[name] = mod          # dataclasses look their module up
+        try:
+            mod_spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def metric_reader(name: str, base: Path = BENCH_DIR) -> Callable:
     """The ``read(run)`` function of ``<base>/metrics/<name>.py``."""
     f = base / "metrics" / f"{name}.py"
     if not f.is_file():
         raise FileNotFoundError(f"no reader for metric {name!r} at {f}")
-    mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), f)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module("chipbench_metric_", f).read
+
+
+def arch_module(model_type: Optional[str], base: Path = BENCH_DIR):
+    """The architecture plug-in ``<base>/arch/<model_type>.py``."""
+    f = base / "arch" / f"{model_type}.py"
+    if not model_type or not f.is_file():
+        known = sorted(p.stem for p in (base / "arch").glob("*.py"))
+        raise KeyError(f"unknown model_type {model_type!r}; known: {known}")
+    return _load_module("chipbench_arch_", f)
 
 
 def read_metrics(metrics: List[Metric], run, base: Path = BENCH_DIR

@@ -1,6 +1,7 @@
 """The comparison that decides ``correct``.
 
-For each judged request, the float32 reference (``reference.py``) runs
+For each judged request, the float32 reference (the ``forward`` of the
+configuration's architecture plug-in, ``arch/<model_type>.py``) runs
 once over its prompt and served tokens, teacher-forced on those tokens
 and on the routing the program served each decode step with.  Three
 numbers over every judged decode step:
@@ -39,9 +40,15 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from . import reference
-
 NUMBERS = ("token_gap", "logit_err", "route_gap")
+
+
+@dataclass
+class RefOut:
+    """What a plug-in's ``forward`` returns."""
+    logits: np.ndarray               # (P, V) at the judged positions
+    routing: Dict[int, np.ndarray]   # layer -> (P, k) experts used there
+    router: Dict[int, np.ndarray]    # layer -> (P, E) router logits there
 
 
 @dataclass
@@ -65,7 +72,7 @@ def _kth(x: np.ndarray, k: int) -> float:
     return float(np.partition(x, -k)[-k])
 
 
-def step_readings(ref: reference.RefOut, tokens: np.ndarray,
+def step_readings(ref: RefOut, tokens: np.ndarray,
                   logits: Optional[Sequence[np.ndarray]],
                   routing: Dict[int, np.ndarray], k: int
                   ) -> Dict[str, List[float]]:
@@ -94,23 +101,26 @@ def _positions(s: Served):
     return seq, np.arange(t0, t0 + s.steps, dtype=np.int32)
 
 
-def program_readings(arch, params, s: Served) -> Dict[str, List[float]]:
+def program_readings(plugin, arch, params, s: Served
+                     ) -> Dict[str, List[float]]:
+    """The compared numbers of one served request: ``plugin`` is the
+    architecture plug-in, ``arch`` its ``Arch`` of the configuration."""
     if s.steps < 1:
         return {n: [] for n in NUMBERS}
     seq, pos = _positions(s)
     forced = {li: np.stack([step[li] for step in s.routing])
-              for li in range(arch.num_layers)}
-    ref = reference.forward(arch, params, seq, pos, forced=forced)
+              for li in sorted(s.routing[0])}
+    ref = plugin.forward(arch, params, seq, pos, forced=forced)
     return step_readings(ref, s.tokens[1:], s.logits, forced, arch.top_k)
 
 
-def control_readings(arch, params, s: Served, mode: str = "fp8"
+def control_readings(plugin, arch, params, s: Served, mode: str = "fp8"
                      ) -> Dict[str, List[float]]:
     if s.steps < 1:
         return {n: [] for n in NUMBERS}
     seq, pos = _positions(s)
-    ctl = reference.forward(arch, params, seq, pos, mode=mode)
-    ref = reference.forward(arch, params, seq, pos, forced=ctl.routing)
+    ctl = plugin.forward(arch, params, seq, pos, mode=mode)
+    ref = plugin.forward(arch, params, seq, pos, forced=ctl.routing)
     return step_readings(ref, ctl.logits.argmax(-1), list(ctl.logits),
                          ctl.routing, arch.top_k)
 

@@ -12,11 +12,12 @@ def read(run):
     from chipbench import flops, kernels
     cfg = run.cfg
     item = 2 if cfg.dtype == "bfloat16" else 4
+    moe_layers = sum(ff == "moe" for _, ff in cfg.layer_kinds())
     work = []
     for n in run.prefills:
         experts = min(cfg.num_experts, n * cfg.top_k)
         call = flops.gemm_call_work(cfg.d_model, cfg.d_expert_resolved, item,
                                     n * cfg.top_k, experts, n)
-        work += [call] * cfg.num_layers
+        work += [call] * moe_layers
     return kernels.roofline_share(kernels.least_s(run, work),
                                   kernels.prefill_kernel_events(run.trace))

@@ -41,7 +41,8 @@ def main(argv=None) -> int:
             out = driver.run(args.workload, seed, args.seconds, False,
                              lambda m: print(m, file=sys.stderr, flush=True),
                              t_start=time.perf_counter(), keep_served=True)
-            parts = [yardstick.program_readings(out["arch"], out["params"], s)
+            ref = (out["plugin"], out["arch"], out["params"])
+            parts = [yardstick.program_readings(*ref, s)
                      for s in out["served"]]
             row = {"workload": args.workload, "seed": seed,
                    "program": yardstick.summarize(parts),
@@ -54,7 +55,7 @@ def main(argv=None) -> int:
                                out["result"]["metrics"].items()}}
             if seed in controls:
                 row["control"] = yardstick.summarize([
-                    yardstick.control_readings(out["arch"], out["params"], s)
+                    yardstick.control_readings(*ref, s)
                     for s in out["served"]])
             line = json.dumps(row)
             print(line, flush=True)

@@ -1,6 +1,7 @@
 """A tiny benchmark tree for CPU tests: one small Granite-shaped model in
 bfloat16, a one-client and a three-client closed loop, and the real
-metric readers, laid out exactly like ``benchmarks/chip``."""
+architecture plug-ins and metric readers, laid out exactly like
+``benchmarks/chip``."""
 from __future__ import annotations
 
 import json
@@ -16,6 +17,7 @@ for p in (str(BENCH), str(ROOT / "src")):
 
 CONFIG = {
     "name": "tiny", "source": "a small model of the Granite-MoE shape",
+    "model_type": "granitemoe",
     "hidden_size": 64, "intermediate_size": 32, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2,
     "num_local_experts": 8, "num_experts_per_tok": 2, "vocab_size": 512,
@@ -49,7 +51,9 @@ def build(base: Path) -> Path:
     """Write the tiny tree under ``base``; returns its BENCHMARK.json."""
     for d in ("configs", "traffic", "limits"):
         (base / d).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", base / "metrics", dirs_exist_ok=True)
+    for d in ("arch", "metrics"):
+        shutil.copytree(BENCH / d, base / d, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (base / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
     for name, clients in (("solo", 1), ("batch", 3)):
         mix = dict(MIX, name=name, clients=clients, max_batch=clients)

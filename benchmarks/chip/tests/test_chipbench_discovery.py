@@ -1,14 +1,16 @@
-"""A new configuration, traffic mix and per-layer metric are new files,
-found by name: no file that is already there changes."""
+"""A new configuration, traffic mix, per-layer metric and architecture are
+new files, found by name: no file that is already there changes."""
 from __future__ import annotations
 
 import json
 import shutil
+import time
 
+import numpy as np
 import pytest
 
 import chipbench_tiny
-from chipbench import config, spec, traffic
+from chipbench import config, driver, spec, traffic
 
 
 @pytest.fixture
@@ -74,6 +76,10 @@ def test_unknown_names_are_errors(tree):
         config.load("no-such-model", base)
     with pytest.raises(FileNotFoundError):
         spec.metric_reader("no_such_metric", base)
+    raw = dict(chipbench_tiny.CONFIG, name="alien", model_type="alien")
+    (base / "configs" / "alien.json").write_text(json.dumps(raw))
+    with pytest.raises(KeyError, match="'alien'; known: .*granitemoe"):
+        config.load("alien", base)
 
 
 def test_the_real_benchmark_files_resolve():
@@ -89,6 +95,70 @@ def test_the_real_benchmark_files_resolve():
 
 def test_bench_dir_alone_is_enough(tmp_path):
     """The package finds everything under its own directory."""
-    shutil.copytree(spec.BENCH_DIR / "configs", tmp_path / "configs")
+    for d in ("configs", "arch"):
+        shutil.copytree(spec.BENCH_DIR / d, tmp_path / d)
     assert config.load("granite3-3b-a800m-8L", tmp_path).model.num_layers \
         == 8
+
+
+# A new architecture, as a later change would bring it: the Granite
+# plug-in copied at tiny size, with its routed experts kept on the host as
+# ``numpy`` leaves and brought to the device a layer at a time by its
+# reference.  ``broken`` leaves the experts' output out of the reference.
+TOY_EDITS = {
+    "clean": [
+        ("def make_params(cfg, seed: int) -> dict:\n"
+         "    params = _maker(cfg)(seed_key(seed))\n"
+         "    jax.block_until_ready(params)\n",
+         "def make_params(cfg, seed: int) -> dict:\n"
+         "    params = _maker(cfg)(seed_key(seed))\n"
+         "    jax.block_until_ready(params)\n"
+         "    ff = params[\"layers\"][0][\"ff\"]\n"
+         "    for name in (\"w_gate\", \"w_up\", \"w_down\"):\n"
+         "        ff[name] = np.asarray(ff[name])\n"),
+        ("            layers, jnp.int32(li), x, positions,",
+         "            jax.tree.map(lambda a: jnp.asarray(a[li:li + 1]), "
+         "layers),\n            jnp.int32(0), x, positions,"),
+    ],
+}
+TOY_EDITS["broken"] = TOY_EDITS["clean"] + [
+    ("            x = x + y.astype(dt)\n", "")]
+
+
+@pytest.mark.parametrize("variant,expect", [("clean", True),
+                                            ("broken", False)])
+def test_new_architecture_is_new_files(tree, variant, expect):
+    base, spec_file, before = tree
+    toy = (base / "arch" / "granitemoe.py").read_text()
+    for old, new in TOY_EDITS[variant]:
+        assert toy.count(old) == 1, old
+        toy = toy.replace(old, new)
+    (base / "arch" / "toy.py").write_text(toy)
+    cfg = dict(chipbench_tiny.CONFIG, name="toy", model_type="toy")
+    (base / "configs" / "toy.json").write_text(json.dumps(cfg))
+    (base / "limits" / "toy.solo.json").write_text(
+        json.dumps({"limits": chipbench_tiny.LIMITS}))
+    raw = json.loads(spec_file.read_text())
+    raw["configs"].append(dict(raw["configs"][0], name="toy",
+                               file="configs/toy.json"))
+    raw["workloads"].append({"name": "toy.solo", "config": "toy",
+                             "traffic": "solo", "chips": 1,
+                             "why": "a new architecture"})
+    spec_file.write_text(json.dumps(raw))
+
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
+    out = driver.run("toy.solo", 2**33 + 7, 2.0, False, lambda m: None,
+                     t_start=time.perf_counter(), require_chip=False,
+                     base=base, spec_file=spec_file, compile_cache=False,
+                     keep_served=True)
+    res = out["result"]
+    assert out["plugin"] is config.load("toy", base).plugin
+    assert isinstance(out["params"]["layers"][0]["ff"]["w_gate"],
+                      np.ndarray)
+    assert res["checks"]["judged_steps"]["value"] > 0
+    assert res["correct"] is expect, res["checks"]
+    after = {p: p.read_bytes() for p in before}
+    changed = [p.name for p in before
+               if p != spec_file and after[p] != before[p]]
+    assert changed == []

@@ -1,36 +1,14 @@
-"""Operation and byte counts, checked by hand for Granite-3.0-3B-A800M."""
+"""The grouped expert GEMM's operation and byte counts and the roofline,
+checked by hand at Granite-3.0-3B-A800M's widths.  The whole model's
+count is its architecture plug-in's (``test_chipbench_arch_granitemoe.py``)."""
 from __future__ import annotations
 
 import pytest
 
 import chipbench_tiny  # noqa: F401
-from chipbench import config, flops, peaks
+from chipbench import flops, peaks
 
 D, F = 1536, 512          # hidden size, expert width
-
-
-@pytest.fixture(scope="module")
-def cfg():
-    return config.load("granite3-3b-a800m-8L").model
-
-
-def test_active_flops_by_hand(cfg):
-    # per layer: q (1536x1536) + k, v (1536x512 each) + o (1536x1536)
-    # = 6,291,456 weights; router 1536x40 = 61,440; 8 experts x 3 x
-    # 1536 x 512 = 18,874,368 -> 25,227,264 weights x 2 FLOPs
-    per_layer = 2 * (6_291_456 + 61_440 + 18_874_368)
-    head = 2 * 1536 * 49155
-    assert flops.active_matmul_flops(cfg) == 8 * per_layer + head
-
-
-def test_attention_and_prompt_flops_by_hand(cfg):
-    # 24 heads x 64 dims, QK and PV: 4 x 1536 FLOPs per key and layer
-    assert flops.attention_flops(cfg, 100) == 8 * 4 * 1536 * 100
-    n = 3
-    assert flops.prompt_flops(cfg, n) == (
-        3 * flops.active_matmul_flops(cfg) + 8 * 4 * 1536 * (1 + 2 + 3))
-    assert flops.decode_flops(cfg, 10) == (
-        flops.active_matmul_flops(cfg) + 8 * 4 * 1536 * 10)
 
 
 def test_decode_wave_roofline_by_hand():

@@ -90,7 +90,8 @@ def test_readers_on_a_recorded_trace():
     from types import SimpleNamespace as NS
     import numpy as np
     from chipbench import config, flops, peaks, spec
-    cfg = config.load("granite3-3b-a800m-8L").model
+    bench_cfg = config.load("granite3-3b-a800m-8L")
+    cfg, plugin = bench_cfg.model, bench_cfg.plugin
     pk = peaks.for_kind("TPU v5 lite")
     ms = 1_000_000
     progs = [T.Ev("jit_fn(1)", 10 * ms, 60 * ms),             # prefill
@@ -104,7 +105,7 @@ def test_readers_on_a_recorded_trace():
     tr = T.Trace([T.DeviceTrace(ops=ops, programs=progs)], spans,
                  (0, 1000 * ms))
     lr = NS(true=np.arange(8)[None], waves=[[(e, e) for e in range(8)]])
-    run = NS(trace=tr, peaks=pk, cfg=cfg, prefills=[1000],
+    run = NS(trace=tr, peaks=pk, cfg=cfg, plugin=plugin, prefills=[1000],
              records=[NS(layers=[lr])], clients=[], window=(0.0, 1.0))
     read = lambda name: spec.metric_reader(name)(run)  # noqa: E731
     assert read("prefill_ms_p50") == pytest.approx(60.0)
@@ -117,5 +118,5 @@ def test_readers_on_a_recorded_trace():
     assert read("moe_gemm_roofline.decode") == pytest.approx(
         100 * least / 1e-4)
     assert read("mfu.prefill") == pytest.approx(
-        100 * flops.prompt_flops(cfg, 1000) / (0.070 * pk.bf16_flops))
+        100 * plugin.prompt_flops(cfg, 1000) / (0.070 * pk.bf16_flops))
     assert read("device_idle_share") == pytest.approx(100 * (1 - 0.0401))
