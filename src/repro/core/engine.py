@@ -50,13 +50,17 @@ import numpy as np
 from repro.kernels.moe_gemm import (combine_topk, grouped_topk_contrib,
                                     grouped_topk_contrib_packed)
 from repro.models import prefill
-from repro.models.blocks import block_decode
+from repro.models.blocks import (block_decode, block_seq, moe_branch,
+                                 residual)
 from repro.models.config import MOE_FF, NO_FF, ModelConfig
-from repro.models.layers import apply_norm, embed
-from repro.models.moe import route
-from repro.models.transformer import layer_params, logits_from_hidden
+from repro.models.layers import apply_norm
+from repro.models.moe import (expert_chunk, grouped_block_contrib, route,
+                              shared_expert)
+from repro.models.transformer import (at_rep, embed_tokens, layer_params,
+                                      logits_from_hidden)
 from repro.quant.quantize import shadow_nbytes
-from repro.quant.transport import resolve_policy, transport_params
+from repro.quant.transport import (EXPERT_WEIGHT_NAMES, resolve_policy,
+                                   transport_params)
 
 from .align import AlignmentPolicy
 from .predictor import (FrequencyPredictor, GateExtrapolator, RandomPredictor,
@@ -162,33 +166,91 @@ class Trace:
 # shape — constructing engines stays cheap and the test suite compiles
 # each step once, not once per engine.  Parameters enter as pytree
 # arguments (never closures), so transport-round-tripped and shadow
-# weight sets reuse the same executables too.
-_embed_token = jax.jit(lambda p, t: embed(t[:, None], p["embed"]))
+# weight sets reuse the same executables too.  A layer's parameters
+# enter as its pattern position's stack and its repeat index, sliced
+# inside the program: the engine holds the caller's one copy of the
+# weights, never a per-layer copy beside it.
+def _named(fn, name: str):
+    """``fn`` under ``name``: the device trace names a jitted program
+    after its function (``jit_<name>``)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _embed_token(cfg: ModelConfig) -> object:
+    return jax.jit(lambda p, t: embed_tokens(cfg, p, t[:, None]))
 
 
 @functools.lru_cache(maxsize=None)
 def _block_step(cfg: ModelConfig, kinds) -> object:
     """Jitted non-MoE block decode (mixer + dense/no FFN)."""
-    def fn(lp, x, cache, pos):
-        return block_decode(cfg, lp, kinds, x, cache, pos)
+    def fn(stack, r, x, cache, pos):
+        return block_decode(cfg, at_rep(stack, r), kinds, x, cache, pos)
     return jax.jit(fn)
 
 
 @functools.lru_cache(maxsize=None)
 def _mixer_router_step(cfg: ModelConfig, kinds) -> object:
     """Jitted MoE-layer prefix: mixer + residual (no FFN), post-norm
-    router input, and the top-k routing decision — everything between
-    the previous layer and the expert waves, fused into one dispatch.
-    The expert FFNs themselves run from worker slots (see
-    ``_serve_and_compute``); only the gate lives on the main node."""
-    def fn(lp, x, cache, pos):
+    router input, the top-k routing decision and the shared expert's
+    output — everything between the previous layer and the expert
+    waves, fused into one dispatch, named after the mixer
+    (``jit_mamba_layer_step`` / ``jit_attn_layer_step`` in a trace).
+    The routed expert FFNs run from worker slots (see
+    ``_serve_and_compute``); only the gate and the shared expert live
+    on the main node."""
+    def fn(stack, r, x, cache, pos):
+        lp = at_rep(stack, r)
         x, cache, _ = block_decode(cfg, lp, (kinds[0], NO_FF), x, cache,
                                    pos)
         with jax.named_scope("router"):
             h = apply_norm(cfg, x, lp["norm2"])[:, 0]      # router input
             topk_idx, topk_gate, _ = route(cfg, lp["ff"], h)
-        return x, cache, h, topk_idx, topk_gate
+        return x, cache, h, topk_idx, topk_gate, shared_expert(lp["ff"], h)
+    return jax.jit(_named(fn, f"{kinds[0]}_layer_step"))
+
+
+@functools.lru_cache(maxsize=None)
+def _moe_residual(cfg: ModelConfig) -> object:
+    """The routed output (f32), the shared expert's and the residual:
+    ``x + (routed + shared) * residual_multiplier``."""
+    def fn(x, y, shared):
+        out = moe_branch(y.astype(x.dtype), shared)
+        return residual(cfg, x, out.reshape(x.shape))
     return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _seq_mixer_router(cfg: ModelConfig, kinds, max_cache_len: int
+                      ) -> object:
+    """Prefill of one routed layer up to its experts (streamed
+    prefill): the mixer over the prompt with its cache, the router's
+    top-k over every prompt row and the shared expert's output."""
+    def fn(stack, r, x, positions):
+        lp = at_rep(stack, r)
+        x, _, cache = block_seq(cfg, lp, (kinds[0], NO_FF), x, positions,
+                                make_cache=True, max_cache_len=max_cache_len)
+        h = apply_norm(cfg, x, lp["norm2"])
+        h = h.reshape(-1, h.shape[-1])
+        topk_idx, topk_gate, _ = route(cfg, lp["ff"], h)
+        return x, cache, h, topk_idx, topk_gate, shared_expert(lp["ff"], h)
+    return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _seq_block(cfg: ModelConfig, kinds, max_cache_len: int) -> object:
+    """Prefill of one layer without routed experts (streamed prefill)."""
+    def fn(stack, r, x, positions):
+        x, _, cache = block_seq(cfg, at_rep(stack, r), kinds, x, positions,
+                                make_cache=True, max_cache_len=max_cache_len)
+        return x, cache
+    return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _last_logits(cfg: ModelConfig) -> object:
+    return jax.jit(lambda p, x: logits_from_hidden(cfg, p, x[:, -1:])[:, 0])
 
 
 @functools.lru_cache(maxsize=None)
@@ -239,6 +301,25 @@ def slice_cache_list(cache_list, i: int):
     if hasattr(cache_list, "member"):      # paged batch view
         return cache_list.member(i)
     return [jax.tree.map(lambda a: a[i:i + 1], c) for c in cache_list]
+
+
+def _without_routed(sub: dict) -> dict:
+    """A pattern position's parameters without its routed experts (the
+    store holds those)."""
+    ff = sub.get("ff")
+    if not isinstance(ff, dict) or "router" not in ff:
+        return sub
+    return dict(sub, ff={k: v for k, v in ff.items()
+                         if k not in EXPERT_WEIGHT_NAMES})
+
+
+def host_held_experts(cfg: ModelConfig, params) -> bool:
+    """Whether the routed experts arrive as host ``numpy`` leaves: the
+    engine then never puts them on the device."""
+    pattern, _ = cfg.pattern()
+    return any(isinstance(params["layers"][pos]["ff"][n], np.ndarray)
+               for pos, kinds in enumerate(pattern) if kinds[1] == MOE_FF
+               for n in EXPERT_WEIGHT_NAMES)
 
 
 class ODMoEEngine:
@@ -308,6 +389,13 @@ class ODMoEEngine:
         # move fewer bytes.
         self.transport = resolve_policy(transport)
         self.moe_layers = moe_layer_indices(cfg)
+        # routed experts given as host ``numpy`` leaves stay there: the
+        # store aliases them, decode loads them into worker slots and
+        # prefill streams them a block at a time (``_prefill_streamed``)
+        self.host_experts = host_held_experts(cfg, params)
+        if self.host_experts and not self.transport.trivial:
+            raise ValueError("host-held experts ship at their own "
+                             "precision (fp32 transport)")
         # ``compute_vs_ship``: None = always ship (the historical
         # behavior); True / a float enables MoNDE-style per-expert
         # pricing on the reload path — a cold expert whose host-memory
@@ -393,10 +481,17 @@ class ODMoEEngine:
                                   horizon=peek_horizon,
                                   physical=physical_loading,
                                   packed=packed_slots))
-        # per-layer parameter views sliced once (params never mutate);
-        # the decode loop re-slicing them every token was pure overhead
-        self._layer_params = [layer_params(cfg, self.params, li)
-                              for li in range(cfg.num_layers)]
+        # each layer's parameters: its pattern position's stack (the
+        # caller's arrays, routed experts left to the store) and its
+        # repeat index, sliced inside the jitted steps
+        pattern, reps = cfg.pattern()
+        self._stacks = [_without_routed(sub) for sub in self.params["layers"]]
+        rep_idx = [jnp.int32(r) for r in range(reps)]
+        self._at = [(self._stacks[li % len(pattern)],
+                     rep_idx[li // len(pattern)])
+                    for li in range(cfg.num_layers)]
+        # the most routed-expert bytes one prefill layer streamed
+        self.prefill_stream_peak = 0
         # (rows, V) logits of the latest decode step, left on the device;
         # ``keep_logits`` also keeps every step's in the traces, which
         # holds V floats per token on the device, so only checks ask
@@ -456,10 +551,17 @@ class ODMoEEngine:
         control) and supplies the request id the page table is keyed by.
         """
         with span("prefill", prompt_len=int(batch["tokens"].shape[1])):
-            logits, state = prefill(self.cfg, self.params, batch,
-                                    max_cache_len, moe_method="grouped")
+            if self.host_experts:
+                logits, cache_list = self._prefill_streamed(batch,
+                                                            max_cache_len)
+                state = {"pos": jnp.full(batch["tokens"].shape[:1],
+                                         batch["tokens"].shape[1],
+                                         jnp.int32)}
+            else:
+                logits, state = prefill(self.cfg, self.params, batch,
+                                        max_cache_len, moe_method="grouped")
+                cache_list = self._unstack(state["caches"])
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            cache_list = self._unstack(state["caches"])
             if kv_pool is not None:
                 if batch["tokens"].shape[0] != 1 or rid is None:
                     raise ValueError("paged prefill adopts one request "
@@ -467,6 +569,45 @@ class ODMoEEngine:
                 cache_list = kv_pool.adopt(rid, cache_list,
                                            batch["tokens"].shape[1])
             return token, cache_list, state["pos"]
+
+    def _prefill_streamed(self, batch, max_cache_len: int):
+        """Prefill with the routed experts held on the host: layer by
+        layer, each routed layer's experts streamed from the store a
+        block at a time (the blocks its prompt routes to), computed with
+        the grouped expert GEMM and dropped after the layer.  Returns
+        ``(last-token logits, per-layer caches)``."""
+        cfg = self.cfg
+        tokens = jnp.asarray(batch["tokens"])
+        b, t = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+        x = embed_tokens(cfg, self.params, tokens)
+        g = expert_chunk(cfg.num_experts)
+        cache_list = []
+        for li, kinds in enumerate(cfg.layer_kinds()):
+            stack, r = self._at[li]
+            if kinds[1] != MOE_FF:
+                x, cache = _seq_block(cfg, kinds, max_cache_len)(
+                    stack, r, x, positions)
+                cache_list.append(cache)
+                continue
+            x, cache, h, topk_idx, topk_gate, shared = _seq_mixer_router(
+                cfg, kinds, max_cache_len)(stack, r, x, positions)
+            cache_list.append(cache)
+            routed = np.unique(np.asarray(topk_idx))
+            blocks = sorted({int(e) // g * g for e in routed})
+            nbytes = len(blocks) * g * self.store.expert_bytes
+            self.prefill_stream_peak = max(self.prefill_stream_peak, nbytes)
+            with span("prefill_stream", layer=li, experts=int(routed.size),
+                      nbytes=nbytes):
+                contrib = None
+                for lo in blocks:
+                    block = self.store.stream_block(li, lo, g)
+                    c = grouped_block_contrib(
+                        h, [block[n] for n in EXPERT_WEIGHT_NAMES], lo,
+                        topk_idx, topk_gate)
+                    contrib = c if contrib is None else contrib + c
+                x = _moe_residual(cfg)(x, combine_topk(contrib), shared)
+        return _last_logits(cfg)(self.params, x), cache_list
 
     # ------------------------------------------------------------ generate
     def generate(self, batch, num_tokens: int,
@@ -599,7 +740,7 @@ class ODMoEEngine:
             cfg = self.cfg
             if self.faults is not None:
                 self.faults.apply(step_idx, self.sched.state, self.slots)
-            x = _embed_token(self.params, token)
+            x = _embed_token(cfg)(self.params, token)
             pending: Dict[int, np.ndarray] = dict(preds)
             # SEP predictions cover the whole token up front: queue their
             # fetches NOW so transfers overlap all the compute before each
@@ -609,20 +750,22 @@ class ODMoEEngine:
                                       skip=self._resident_skip())
             moe_i = -1
             for li, kinds in enumerate(cfg.layer_kinds()):
-                lp = self._layer_params[li]
+                stack, r = self._at[li]
                 if kinds[1] != MOE_FF:
                     x, cache_list[li], _ = _block_step(cfg, kinds)(
-                        lp, x, cache_list[li], pos)
+                        stack, r, x, cache_list[li], pos)
                     continue
                 moe_i += 1
-                # mixer + residual + router input + gate, one dispatch
-                x, cache_list[li], h, topk_idx, topk_gate = \
-                    _mixer_router_step(cfg, kinds)(lp, x, cache_list[li],
-                                                   pos)
+                # mixer + residual + router input + gate + shared expert,
+                # one dispatch
+                x, cache_list[li], h, topk_idx, topk_gate, shared = \
+                    _mixer_router_step(cfg, kinds)(stack, r, x,
+                                                   cache_list[li], pos)
                 with span("router_sync", layer=li):
                     true = np.asarray(topk_idx)
                 x = self._moe_bookkeeping(step_idx, li, moe_i, pending,
-                                          true, h, topk_gate, x, rec)
+                                          true, h, topk_gate, shared, x,
+                                          rec)
             if self.prefetch is not None:
                 self.prefetch.finish_token(step_idx)
             token, self.last_logits = _logits_argmax(cfg)(self.params, x)
@@ -662,7 +805,7 @@ class ODMoEEngine:
                   rids=joined(self.slots.request_context)):
             if self.faults is not None:
                 self.faults.apply(step_idx, self.sched.state, self.slots)
-            x = _embed_token(self.params, tokens.reshape(-1))
+            x = _embed_token(cfg)(self.params, tokens.reshape(-1))
             pos_rows = (pos[:, None]
                         + jnp.arange(s_w, dtype=pos.dtype)).reshape(-1)
             pending: Dict[int, np.ndarray] = dict(preds)
@@ -672,7 +815,7 @@ class ODMoEEngine:
             spec_caches: Dict[int, dict] = {}
             moe_i = -1
             for li, kinds in enumerate(cfg.layer_kinds()):
-                lp = self._layer_params[li]
+                stack, r = self._at[li]
                 # each wave row verifies against its own copy of the
                 # request's cache (seeded with the earlier rows' K/V
                 # inside the spec step); the commit below SELECTS the
@@ -682,16 +825,17 @@ class ODMoEEngine:
                                     cache_list[li])
                 if kinds[1] != MOE_FF:
                     x, spec_caches[li] = _spec_block_step(cfg, kinds, s_w)(
-                        lp, x, repl, pos_rows)
+                        stack, r, x, repl, pos_rows)
                     continue
                 moe_i += 1
-                x, spec_caches[li], h, topk_idx, topk_gate = \
+                x, spec_caches[li], h, topk_idx, topk_gate, shared = \
                     _spec_mixer_router_step(cfg, kinds, s_w)(
-                        lp, x, repl, pos_rows)
+                        stack, r, x, repl, pos_rows)
                 with span("router_sync", layer=li):
                     true = np.asarray(topk_idx)
                 x = self._moe_bookkeeping(step_idx, li, moe_i, pending,
-                                          true, h, topk_gate, x, rec)
+                                          true, h, topk_gate, shared, x,
+                                          rec)
             if self.prefetch is not None:
                 self.prefetch.finish_token(step_idx)
             verified, self.last_logits = _logits_argmax(cfg)(self.params, x)
@@ -718,11 +862,12 @@ class ODMoEEngine:
         return lambda layer, e: self.slots.worker_with(layer, e) is not None
 
     def _moe_bookkeeping(self, step_idx, li, moe_i, pending, true, h,
-                         topk_gate, x, rec: TokenRecord):
+                         topk_gate, shared, x, rec: TokenRecord):
         """Everything around one MoE layer's expert waves, shared by the
         production and the retired decode paths: on-the-fly predictors,
-        serve + compute, trace recording and the cacheless eviction
-        rule (or, under residency, the opportunistic release)."""
+        serve + compute, the shared expert and the residual, trace
+        recording and the cacheless eviction rule (or, under residency,
+        the opportunistic release)."""
         with span("serve", layer=li, experts=int(np.unique(true).size)):
             b = true.shape[0]
             # on-the-fly predictors key off the router input
@@ -751,7 +896,7 @@ class ODMoEEngine:
             if self.residency is not None:
                 # realized routing feeds the gate-statistics policy
                 self.slots.observe_gates(li, true, np.asarray(topk_gate))
-            x = x + y[:, None].astype(x.dtype)
+            x = _moe_residual(self.cfg)(x, y, shared)
             # prompt eviction — cacheless rule.  Every worker that took a
             # load this layer (predicted or reload, group or spill) drops
             # its experts, so a mispredicted never-used resident cannot
@@ -781,7 +926,7 @@ class ODMoEEngine:
         cfg = self.cfg
         if self.faults is not None:
             self.faults.apply(step_idx, self.sched.state, self.slots)
-        x = embed(token[:, None], self.params["embed"])
+        x = embed_tokens(cfg, self.params, token[:, None])
         pending: Dict[int, np.ndarray] = dict(preds)
         moe_i = -1
         for li, kinds in enumerate(cfg.layer_kinds()):
@@ -798,7 +943,8 @@ class ODMoEEngine:
             topk_idx, topk_gate, _ = route(cfg, lp["ff"], h)
             true = np.asarray(topk_idx)
             x = self._moe_bookkeeping(step_idx, li, moe_i, pending, true,
-                                      h, topk_gate, x, rec)
+                                      h, topk_gate,
+                                      shared_expert(lp["ff"], h), x, rec)
         logits = logits_from_hidden(cfg, self.params, x)[:, 0]
         self.last_logits = logits
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_list,
@@ -1086,8 +1232,32 @@ class ODMoEEngine:
             self.prefetch.close()
 
     # ------------------------------------------------------------- memory
-    def memory_report(self) -> dict:
-        """Bytes by node type — the paper's Table 2 part (ii) quantities."""
+    def device_bytes(self, caches=()) -> dict:
+        """Bytes the device holds now, by holder: the non-expert
+        weights, routed-expert arrays of the weights (0 when the experts
+        are host-held), the SEP shadow, the worker slots' residents and
+        ``caches`` (any pytree of live request state), with the most
+        routed-expert bytes one streamed prefill layer moved."""
+        def dev(tree):
+            return sum(int(a.size) * a.dtype.itemsize
+                       for a in jax.tree.leaves(tree)
+                       if isinstance(a, jax.Array))
+        pattern, _ = self.cfg.pattern()
+        routed = [self.params["layers"][pos]["ff"][n]
+                  for pos, kinds in enumerate(pattern) if kinds[1] == MOE_FF
+                  for n in EXPERT_WEIGHT_NAMES]
+        top = {k: v for k, v in self.params.items() if k != "layers"}
+        return {"non_expert": dev(top) + dev(self._stacks),
+                "routed_experts": dev(routed),
+                "shadow": dev(self.shadow.params) if self.shadow else 0,
+                "worker_slots": self.slots.resident_device_bytes(),
+                "caches": dev(caches),
+                "prefill_stream_peak": self.prefill_stream_peak}
+
+    def memory_report(self, caches=()) -> dict:
+        """Bytes by node type — the paper's Table 2 part (ii) quantities
+        — and, under ``device``, what the device holds now
+        (:meth:`device_bytes` of ``caches``)."""
         def nbytes(tree):
             return sum(x.size * x.dtype.itemsize
                        for x in jax.tree.leaves(tree))
@@ -1123,4 +1293,5 @@ class ODMoEEngine:
             # (== expert_bytes for fp32); slots hold this footprint too
             # when packed-resident, full width otherwise
             "expert_transport_bytes": transport_max,
+            "device": self.device_bytes(caches),
         }

@@ -1,9 +1,9 @@
 """Expert-activation predictors: SEP (the paper's) + reproduced baselines.
 
 SEP (Scaled Emulative Prediction): a quantized *shadow* copy of the model
-decodes in parallel and its own observed routing decisions — unfolded
-several layers ahead of the full model — are the predictions.  Baselines
-follow §2.3 / Table 1:
+(held as its quantization codes) decodes in parallel and its own
+observed routing decisions — unfolded several layers ahead of the full
+model — are the predictions.  Baselines follow §2.3 / Table 1:
 
   * ``nextgate``  — feed layer l's router input to layer l+1's gate
                     (Mixtral-Offloading / AdapMoE / DAOP heuristic).
@@ -27,7 +27,7 @@ import numpy as np
 
 from repro.models import prefill
 from repro.models.config import MOE_FF, ModelConfig
-from repro.quant import shadow_params
+from repro.quant.quantize import shadow_codes
 
 from .spans import span
 
@@ -132,7 +132,11 @@ class SEPShadow:
     def __init__(self, cfg: ModelConfig, params, scheme: str = "int8"):
         self.cfg = cfg
         self.scheme = scheme
-        self.params = shadow_params(params, scheme)
+        # the scheme's real codes and scales (``QWeight`` leaves): the
+        # model dequantizes them a layer at a time, and a routed expert
+        # only where a token routes to it, so the shadow takes the
+        # memory of its codes and reads the k experts it routes to
+        self.params = shadow_codes(params, scheme)
         self.state = None
         self.token = None
         # the whole shadow decode step — grouped expert FFNs included —

@@ -48,10 +48,11 @@ import numpy as np
 
 from repro.models.attention import (NEG_INF, _gqa_out, _gqa_scores,
                                     _project_qkv)
-from repro.models.blocks import _apply_ff
+from repro.models.blocks import _apply_ff, residual
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_norm, apply_rope
-from repro.models.moe import route
+from repro.models.moe import route, shared_expert
+from repro.models.transformer import at_rep
 
 
 # ------------------------------------------------------------ verify wave
@@ -111,18 +112,20 @@ def spec_attn_decode(cfg: ModelConfig, params, x, cache, pos, S: int
 
 # The per-layer jitted spec steps mirror the engine's ``_block_step`` /
 # ``_mixer_router_step`` factories: lru-cached on (frozen config, layer
-# kinds, wave width), parameters as pytree arguments, one dispatch per
-# layer per wave.  ``S`` is part of the key because the seeding loop
-# unrolls over it.
+# kinds, wave width), a layer's parameters as its stack and repeat
+# index, one dispatch per layer per wave.  ``S`` is part of the key
+# because the seeding loop unrolls over it.
+
 @functools.lru_cache(maxsize=None)
 def _spec_block_step(cfg: ModelConfig, kinds, S: int) -> object:
     """Jitted non-MoE spec block: multi-position attention + dense/no
     FFN (rows are independent through the FFN, so ``_apply_ff`` is
     reused unchanged)."""
-    def fn(lp, x, cache, pos):
+    def fn(stack, r, x, cache, pos):
+        lp = at_rep(stack, r)
         h = apply_norm(cfg, x, lp["norm1"])
         out, cache = spec_attn_decode(cfg, lp["mixer"], h, cache, pos, S)
-        x = x + out
+        x = residual(cfg, x, out)
         x, _ = _apply_ff(cfg, lp, kinds, x, "dense")
         return x, cache
     return jax.jit(fn)
@@ -135,13 +138,15 @@ def _spec_mixer_router_step(cfg: ModelConfig, kinds, S: int) -> object:
     ``B*S`` wave rows in one dispatch.  The expert FFNs themselves run
     from worker slots via the engine's wave machinery, exactly as in
     one-token decode — a verify wave is just a (B*S)-row batch to it."""
-    def fn(lp, x, cache, pos):
+    def fn(stack, r, x, cache, pos):
+        lp = at_rep(stack, r)
         h = apply_norm(cfg, x, lp["norm1"])
         out, cache = spec_attn_decode(cfg, lp["mixer"], h, cache, pos, S)
-        x = x + out
+        x = residual(cfg, x, out)
         hr = apply_norm(cfg, x, lp["norm2"])[:, 0]
         topk_idx, topk_gate, _ = route(cfg, lp["ff"], hr)
-        return x, cache, hr, topk_idx, topk_gate
+        return x, cache, hr, topk_idx, topk_gate, shared_expert(lp["ff"],
+                                                                hr)
     return jax.jit(fn)
 
 

@@ -124,10 +124,19 @@ class ExpertStore:
             i for i, (_, ff) in enumerate(cfg.layer_kinds()) if ff == MOE_FF]
         self._host: Dict[Tuple[int, int], Dict[str, np.ndarray]] = {}
         self._packed: Dict[Tuple[int, int], Dict[str, PackedWeight]] = {}
+        # each routed layer's (E, d, f) host arrays: views of host
+        # ``numpy`` leaves (the store is then their only holder and
+        # copies nothing), else one copy off the device
+        self._layers: Dict[int, Dict[str, np.ndarray]] = {}
+        pattern, _ = cfg.pattern()
         for li in self.moe_layers:
-            lp = layer_params(cfg, params, li)["ff"]
+            ff = params["layers"][li % len(pattern)]["ff"]
+            r = li // len(pattern)
+            self._layers[li] = {n: np.asarray(ff[n][r])
+                                for n in EXPERT_WEIGHT_NAMES}
             for e in range(cfg.num_experts):
-                host = {n: np.asarray(lp[n][e]) for n in EXPERT_WEIGHT_NAMES}
+                host = {n: self._layers[li][n][e]
+                        for n in EXPERT_WEIGHT_NAMES}
                 self._host[(li, e)] = host
                 codec = self.policy.codec_for(li, e)
                 self._packed[(li, e)] = {
@@ -136,6 +145,14 @@ class ExpertStore:
         self.expert_bytes = int(sum(a.nbytes for a in sample.values()))
         # tile-aligned device layouts (packed-resident mode), built lazily
         self._device_host: Dict[Tuple[int, int], Dict[str, Tuple]] = {}
+
+    def stream_block(self, layer: int, lo: int, n: int
+                     ) -> Dict[str, jax.Array]:
+        """Experts ``lo .. lo + n`` of ``layer`` on the device, one
+        transfer per weight from the contiguous host block (streamed
+        prefill; fp32 transport)."""
+        return jax.device_put({name: w[lo:lo + n]
+                               for name, w in self._layers[layer].items()})
 
     def get_host(self, layer: int, expert: int) -> Dict[str, np.ndarray]:
         return self._host[(layer, expert)]
@@ -539,6 +556,19 @@ class WorkerSlots:
         self.stats["recoveries"] += 1
 
     # -------------------------------------------------------------- memory
+    def resident_device_bytes(self) -> int:
+        """Device bytes the occupied slots hold now."""
+        total = 0
+        for slots in self._slot_data:
+            for data in slots.values():
+                if isinstance(data, DeviceShard):
+                    total += data.nbytes
+                else:
+                    total += sum(int(a.size) * a.dtype.itemsize
+                                 for a in jax.tree.leaves(data)
+                                 if isinstance(a, jax.Array))
+        return total
+
     def transient_packed_bytes(self) -> int:
         """Largest in-flight packed shard during dequantize-on-arrival.
 

@@ -107,6 +107,20 @@ def _bucketed_prefill_step(cfg: ModelConfig, batch_size: int, bucket: int,
     return jax.jit(fn)
 
 
+@functools.lru_cache(maxsize=None)
+def _exact_prefill_step(cfg: ModelConfig, max_cache_len: int,
+                        moe_method: str):
+    """One compiled prefill per (config, window, dispatch) and prompt
+    shape, for decoder-only models the bucketed path cannot pad (an SSM
+    scan would take the pad tokens into its state)."""
+    def fn(params, tokens):
+        logits, _, caches = tf_lib.lm_seq(
+            cfg, params, tokens, make_cache=True,
+            max_cache_len=max_cache_len, moe_method=moe_method)
+        return logits[:, -1], caches
+    return jax.jit(fn)
+
+
 def prefill_cache_info():
     """Compile-cache statistics of the bucketed prefill (misses ==
     compiled executables) — the serving loop's no-per-prompt-recompile
@@ -138,7 +152,8 @@ def prefill(cfg: ModelConfig, params, batch, max_cache_len: int,
     """Process the prompt; return (last-token logits, decode state).
 
     Decoder-only all-attention models take the bucketed jit path (see
-    above); everything else falls back to the eager per-length trace.
+    above), other decoder-only models one jitted program per prompt
+    length, and models with a frontend the eager per-length trace.
     Both produce bit-identical logits and caches, so callers — the
     reference decoder, the engine, the SEP shadow — never observe which
     path ran."""
@@ -164,10 +179,15 @@ def prefill(cfg: ModelConfig, params, batch, max_cache_len: int,
         state = {"caches": caches, "memories": memories,
                  "pos": jnp.full((b,), tokens.shape[1], jnp.int32)}
         return logits, state
+    b, t = tokens.shape
+    if batch.get("frontend_embeds") is None:
+        logits, caches = _exact_prefill_step(cfg, max_cache_len,
+                                             moe_method)(params, tokens)
+        return logits, {"caches": caches,
+                        "pos": jnp.full((b,), t, jnp.int32)}
     logits, aux, caches = tf_lib.lm_seq(
         cfg, params, tokens, frontend_embeds=batch.get("frontend_embeds"),
         make_cache=True, max_cache_len=max_cache_len, moe_method=moe_method)
-    b, t = tokens.shape
     n_front = aux["n_front"]
     state = {"caches": caches,
              "pos": jnp.full((b,), t + n_front, jnp.int32)}

@@ -88,7 +88,11 @@ def _gqa_scores(cfg: ModelConfig, q, k):
     b, t, h, hd = q.shape
     g = h // cfg.num_kv_heads
     qg = q.reshape(b, t, cfg.num_kv_heads, g, hd)
-    s = jnp.einsum("btkgh,bskh->bkgts", qg, k) / jnp.sqrt(hd).astype(q.dtype)
+    s = jnp.einsum("btkgh,bskh->bkgts", qg, k)
+    if cfg.attention_multiplier:
+        s = s * jnp.asarray(cfg.attention_multiplier, q.dtype)
+    else:
+        s = s / jnp.sqrt(hd).astype(q.dtype)
     if cfg.logit_soft_cap:
         s = cfg.logit_soft_cap * jnp.tanh(s / cfg.logit_soft_cap)
     return s
@@ -178,8 +182,11 @@ def attn_seq_blockwise(cfg: ModelConfig, params, x, positions, *,
     vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
     nq, nk = (t + pad_q) // qb, (t + pad_k) // kb
     # (nq, B, qb, kv, g, hd) query blocks / (nk, B, kb, kv, hd) kv blocks
-    qblocks = jnp.moveaxis(
-        qp.reshape(b, nq, qb, kv, g, hd), 1, 0) / jnp.sqrt(hd).astype(q.dtype)
+    qblocks = jnp.moveaxis(qp.reshape(b, nq, qb, kv, g, hd), 1, 0)
+    if cfg.attention_multiplier:
+        qblocks = qblocks * jnp.asarray(cfg.attention_multiplier, q.dtype)
+    else:
+        qblocks = qblocks / jnp.sqrt(hd).astype(q.dtype)
     kblocks = jnp.moveaxis(kp.reshape(b, nk, kb, kv, hd), 1, 0)
     vblocks = jnp.moveaxis(vp.reshape(b, nk, kb, kv, hd), 1, 0)
     qpos_b = jnp.moveaxis(qpos.reshape(b, nq, qb), 1, 0)

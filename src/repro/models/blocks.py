@@ -17,7 +17,7 @@ from . import attention as attn_lib
 from . import mamba as mamba_lib
 from .config import ATTN, DENSE_FF, MAMBA, MOE_FF, NO_FF, ModelConfig
 from .layers import apply_norm, init_mlp, init_norm, swiglu_mlp
-from .moe import init_moe, moe_ff
+from .moe import init_moe, moe_ff, shared_expert
 
 
 # --------------------------------------------------------------------- init
@@ -51,6 +51,19 @@ def init_block_cache(cfg: ModelConfig, kinds: Tuple[str, str], batch: int,
 
 
 # ------------------------------------------------------------------- apply
+def residual(cfg: ModelConfig, x, out):
+    """``x + out``, the branch scaled by the residual multiplier where
+    the configuration has one."""
+    rm = cfg.residual_multiplier
+    return x + (out if rm == 1.0 else out * rm)
+
+
+def moe_branch(routed, shared):
+    """A routed layer's FFN branch: the routed experts' output plus the
+    shared expert's, where there is one."""
+    return routed if shared is None else routed + shared
+
+
 def _apply_ff(cfg: ModelConfig, params, kinds, x, moe_method: str):
     """x: (B, T, d) -> (out, aux)."""
     ff = kinds[1]
@@ -59,12 +72,15 @@ def _apply_ff(cfg: ModelConfig, params, kinds, x, moe_method: str):
     h = apply_norm(cfg, x, params["norm2"])
     if ff == MOE_FF:
         b, t, d = h.shape
-        out, aux = moe_ff(cfg, params["ff"], h.reshape(b * t, d), moe_method)
+        h2 = h.reshape(b * t, d)
+        out, aux = moe_ff(cfg, params["ff"], h2, moe_method)
+        out = moe_branch(out, shared_expert(params["ff"], h2))
         out = checkpoint_name(out.reshape(b, t, d), "tp_out")
         aux = {"load_balance_loss": aux["load_balance_loss"],
                "topk_idx": aux["topk_idx"].reshape(b, t, cfg.top_k)}
-        return x + out, aux
-    return x + checkpoint_name(swiglu_mlp(h, params["ff"]), "tp_out"), {}
+        return residual(cfg, x, out), aux
+    return residual(cfg, x, checkpoint_name(swiglu_mlp(h, params["ff"]),
+                                            "tp_out")), {}
 
 
 def block_seq(cfg: ModelConfig, params, kinds, x, positions, *,
@@ -84,7 +100,7 @@ def block_seq(cfg: ModelConfig, params, kinds, x, positions, *,
                                         max_cache_len)
         # tag the row-parallel matmul output: the remat policy saves it so
         # backward does not RECOMPUTE the forward TP all-reduce
-        x = x + checkpoint_name(out, "tp_out")
+        x = residual(cfg, x, checkpoint_name(out, "tp_out"))
         if memory is not None and "cross" in params:
             hc = apply_norm(cfg, x, params["norm_cross"])
             x = x + attn_lib.cross_attn(cfg, params["cross"], hc, memory)
@@ -92,7 +108,7 @@ def block_seq(cfg: ModelConfig, params, kinds, x, positions, *,
         out, state = mamba_lib.mamba_seq(cfg, params["mixer"], h)
         if make_cache:
             cache = state
-        x = x + checkpoint_name(out, "tp_out")
+        x = residual(cfg, x, checkpoint_name(out, "tp_out"))
     x, aux = _apply_ff(cfg, params, kinds, x, moe_method)
     return x, aux, cache
 
@@ -108,13 +124,13 @@ def block_decode(cfg: ModelConfig, params, kinds, x, cache, pos, *,
             with jax.named_scope("attention"):
                 out, cache = attn_lib.attn_decode(cfg, params["mixer"], h,
                                                   cache, pos)
-            x = x + out
+            x = residual(cfg, x, out)
             if memory is not None and "cross" in params:
                 hc = apply_norm(cfg, x, params["norm_cross"])
                 x = x + attn_lib.cross_attn(cfg, params["cross"], hc, memory)
         else:
             out, cache = mamba_lib.mamba_decode(cfg, params["mixer"], h,
                                                 cache)
-            x = x + out
+            x = residual(cfg, x, out)
     x, aux = _apply_ff(cfg, params, kinds, x, moe_method)
     return x, cache, aux

@@ -47,6 +47,10 @@ class ModelConfig:
     # the full (E*C, d) dispatch tensor per MoE layer (EXPERIMENTS.md
     # §Perf iter 7), expert-parallel sharding moves token bytes instead.
     padded_experts: int = 0
+    # Width of a shared SwiGLU expert that every token of a routed layer
+    # runs beside its top-k experts (0 = none); it sits with the
+    # router on the main node and is never loaded or predicted.
+    d_shared: int = 0
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -59,10 +63,16 @@ class ModelConfig:
 
     # --- attention details ---
     rope_theta: float = 10000.0
-    rope_fraction: float = 1.0       # chatglm "2d rope": rotate only this fraction of head_dim
+    rope_fraction: float = 1.0       # chatglm "2d rope": rotate only this fraction of head_dim; 0 = NoPE
     qkv_bias: bool = False
     sliding_window: int = 0          # 0 = full attention; >0 = ring-buffer window
     logit_soft_cap: float = 0.0
+
+    # --- scalar multipliers (Granite); the defaults leave them out ---
+    embedding_multiplier: float = 1.0   # scales the token embeddings
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0    # scales each branch before its residual add
+    logits_scaling: float = 1.0         # divides the output logits
 
     # --- encoder-decoder ---
     is_encoder_decoder: bool = False
@@ -190,6 +200,7 @@ class ModelConfig:
             if ff == MOE_FF:
                 total += d * self.num_experts  # router
                 total += self.num_experts_padded * 3 * d * self.d_expert_resolved
+                total += 3 * d * self.d_shared
             elif ff == DENSE_FF:
                 total += self._dense_ff_params()
         total += d  # final norm

@@ -11,6 +11,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.quant.quantize import QWeight, materialize
+
 from .config import ModelConfig
 
 
@@ -66,11 +68,17 @@ def swiglu_mlp(x, params):
 
 
 def embed(tokens, params):
-    return jnp.take(params["table"], tokens, axis=0)
+    table = params["table"]
+    if isinstance(table, QWeight):          # the shadow's codes: the rows only
+        return table.take(tokens)
+    return jnp.take(table, tokens, axis=0)
 
 
 def unembed(x, params):
-    return x @ params["table"].T
+    table = params["table"]
+    if isinstance(table, QWeight):          # dequantized once x exists
+        table, x = jax.lax.optimization_barrier((table, x))
+    return x @ materialize(table).T
 
 
 # ------------------------------------------------------------------- rotary

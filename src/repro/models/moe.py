@@ -38,9 +38,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.moe_gemm import combine_topk, grouped_topk_contrib
+from repro.quant.quantize import EXPERT_WEIGHT_NAMES, QWeight, materialize
 
 from .config import ModelConfig
-from .layers import _dense_init
+from .layers import _dense_init, init_mlp, swiglu_mlp
 
 
 # --------------------------------------------------------------------- init
@@ -50,14 +51,45 @@ def init_moe(key, cfg: ModelConfig) -> dict:
     the expert axis divides the tensor-parallel mesh axis."""
     d, f, e = cfg.d_model, cfg.d_expert_resolved, cfg.num_experts
     ep = cfg.num_experts_padded
-    kr, kg, ku, kd = jax.random.split(key, 4)
+    kr, kg, ku, kd, ks = jax.random.split(key, 5)
     dt = jnp.dtype(cfg.dtype)
-    return {
+    p = {
         "router": _dense_init(kr, (d, e), dt),
         "w_gate": _dense_init(kg, (ep, d, f), dt),
         "w_up": _dense_init(ku, (ep, d, f), dt),
         "w_down": _dense_init(kd, (ep, f, d), dt),
     }
+    if cfg.d_shared:
+        p["shared"] = init_mlp(ks, d, cfg.d_shared, dt)
+    return p
+
+
+def shared_expert(params, x):
+    """The shared expert's output for rows ``x`` (``None`` without one):
+    every token runs it beside its routed experts."""
+    if "shared" not in params:
+        return None
+    return swiglu_mlp(x, params["shared"])
+
+
+def expert_chunk(num_experts: int) -> int:
+    """Experts per block when a layer's experts are brought in blocks
+    (prefill): the largest power of two up to 8 that divides the count,
+    so every block has one shape and the grouped GEMM pads none."""
+    g = 8
+    while num_experts % g:
+        g //= 2
+    return g
+
+
+def grouped_block_contrib(x, block, lo: int, topk_idx, topk_gate):
+    """``grouped_topk_contrib`` for the experts ``lo .. lo + len`` of one
+    block: pairs routed elsewhere are masked to exact zeros, so blocks
+    add up to the one call over every expert."""
+    g = block[0].shape[0]
+    idx = topk_idx.astype(jnp.int32) - lo
+    slot = jnp.where((idx >= 0) & (idx < g), idx, -1)
+    return grouped_topk_contrib(x, *block, slot, topk_gate)
 
 
 # ------------------------------------------------------------------- router
@@ -194,12 +226,37 @@ def moe_grouped(cfg: ModelConfig, params, x) -> Tuple[jax.Array, dict]:
     """
     topk_idx, topk_gate, aux = route(cfg, params, x)
     e = cfg.num_experts
-    wg, wu, wd = (params[k][:e] for k in ("w_gate", "w_up", "w_down"))
-    contrib = grouped_topk_contrib(x, wg, wu, wd,
-                                   topk_idx.astype(jnp.int32), topk_gate)
+    if isinstance(params["w_gate"], QWeight):
+        contrib = _grouped_codes(params, x, topk_idx, topk_gate, e)
+    else:
+        wg, wu, wd = (params[k][:e] for k in ("w_gate", "w_up", "w_down"))
+        contrib = grouped_topk_contrib(x, wg, wu, wd,
+                                       topk_idx.astype(jnp.int32), topk_gate)
     out = combine_topk(contrib).astype(x.dtype)
     aux["topk_idx"] = topk_idx
     return out, aux
+
+
+def _grouped_codes(params, x, topk_idx, topk_gate, e: int):
+    """The grouped dispatch on experts held as codes (the SEP shadow):
+    a few rows dequantize only the experts their pairs route to; many
+    rows (prefill) dequantize the layer's experts one block at a time.
+    Per-pair values do not depend on what rode along, so both agree
+    with one call over every dequantized expert."""
+    ws = [params[k] for k in EXPERT_WEIGHT_NAMES]
+    n, k = topk_idx.shape
+    if n * k <= e:
+        flat = topk_idx.reshape(-1)
+        slot = jnp.arange(n * k, dtype=jnp.int32).reshape(n, k)
+        return grouped_topk_contrib(x, *(w.take(flat) for w in ws), slot,
+                                    topk_gate)
+    g = expert_chunk(e)
+    contrib = None
+    for lo in range(0, e, g):
+        c = grouped_block_contrib(x, [w.block(lo, g) for w in ws], lo,
+                                  topk_idx, topk_gate)
+        contrib = c if contrib is None else contrib + c
+    return contrib
 
 
 DISPATCH = {"dense": moe_dense, "scatter": moe_scatter, "einsum": moe_einsum}
@@ -210,6 +267,8 @@ def moe_ff(cfg: ModelConfig, params, x2d, method="scatter",
     """``method`` is a dispatch name or a callable
     ``(cfg, params, x2d) -> (out, aux)`` (e.g. the shard_map all-to-all
     dispatch from ``moe_a2a.make_moe_a2a``)."""
+    if method != "grouped" and isinstance(params["w_gate"], QWeight):
+        params = materialize(params, keep_routed=False)
     if callable(method):
         return method(cfg, params, x2d)
     if method == "dense":

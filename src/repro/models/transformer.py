@@ -16,6 +16,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.quant.quantize import QWeight, materialize
+
 from .blocks import block_decode, block_seq, init_block, init_block_cache
 from .config import ModelConfig
 from .layers import apply_norm, embed, init_embedding, init_norm, unembed
@@ -57,6 +59,24 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Tuple:
     return tuple(out)
 
 
+def layer_weights(lp, h):
+    """``(lp, h)`` with the shadow's codes in ``lp`` dequantized, once
+    the layer's input ``h`` exists: the barrier keeps XLA from
+    dequantizing every layer's weights at the start of the program."""
+    if not any(isinstance(w, QWeight) for w in jax.tree.leaves(
+            lp, is_leaf=lambda w: isinstance(w, QWeight))):
+        return lp, h
+    lp, h = jax.lax.optimization_barrier((lp, h))
+    return materialize(lp), h
+
+
+def at_rep(stack, r):
+    """Repeat ``r`` of a pattern position's stacked parameters: one
+    layer's, sliced inside a jitted step so that no per-layer copy of
+    the weights is kept beside the stack."""
+    return jax.tree.map(lambda a: a[r], stack)
+
+
 def layer_params(cfg: ModelConfig, params, layer_idx: int):
     """Unstacked parameters of a single layer (used by the OD-MoE engine)."""
     pattern, reps = cfg.pattern()
@@ -65,10 +85,18 @@ def layer_params(cfg: ModelConfig, params, layer_idx: int):
 
 
 # ------------------------------------------------------------------ embeds
+def embed_tokens(cfg: ModelConfig, params, tokens):
+    """Token embeddings, scaled by the embedding multiplier where the
+    configuration has one."""
+    x = embed(tokens, params["embed"])
+    em = cfg.embedding_multiplier
+    return x if em == 1.0 else x * em
+
+
 def input_embeddings(cfg: ModelConfig, params, tokens,
                      frontend_embeds: Optional[jax.Array] = None):
     """Token embeddings, with projected modality embeddings prepended."""
-    x = embed(tokens, params["embed"])
+    x = embed_tokens(cfg, params, tokens)
     n_front = 0
     if cfg.frontend and frontend_embeds is not None:
         proj = params["frontend_proj"]
@@ -81,8 +109,11 @@ def input_embeddings(cfg: ModelConfig, params, tokens,
 def logits_from_hidden(cfg: ModelConfig, params, x):
     x = apply_norm(cfg, x, params["final_norm"])
     if cfg.tie_embeddings:
-        return unembed(x, params["embed"])
-    return x @ params["head"]["w"]
+        logits = unembed(x, params["embed"])
+    else:
+        logits = x @ materialize(params["head"]["w"])
+    ls = cfg.logits_scaling
+    return logits if ls == 1.0 else logits / ls
 
 
 # ---------------------------------------------------------------- sequence
@@ -118,8 +149,9 @@ def lm_seq(cfg: ModelConfig, params, tokens, *,
             h = residual_constraint(h)
         auxs, caches = [], []
         for i, kinds in enumerate(pattern):
+            lp, h = layer_weights(slices[i], h)
             h, aux, cache = block_seq(
-                cfg, slices[i], kinds, h, positions,
+                cfg, lp, kinds, h, positions,
                 moe_method=moe_method, make_cache=make_cache,
                 max_cache_len=max_cache_len)
             auxs.append(aux)
@@ -156,7 +188,7 @@ def lm_decode(cfg: ModelConfig, params, token, caches, pos, *,
     bytes per device on every decode shape; EXPERIMENTS.md §Perf 9).
     """
     pattern, reps = cfg.pattern()
-    x = embed(token[:, None], params["embed"])
+    x = embed_tokens(cfg, params, token[:, None])
 
     def body(carry, lp):
         h, lc_all, r = carry
@@ -164,7 +196,8 @@ def lm_decode(cfg: ModelConfig, params, token, caches, pos, *,
             a, r, axis=0, keepdims=False), lc_all)
         new_caches, auxs = [], []
         for i, kinds in enumerate(pattern):
-            h, c, aux = block_decode(cfg, lp[i], kinds, h, lc[i], pos,
+            lpi, h = layer_weights(lp[i], h)
+            h, c, aux = block_decode(cfg, lpi, kinds, h, lc[i], pos,
                                      moe_method=moe_method)
             new_caches.append(c)
             auxs.append(aux)

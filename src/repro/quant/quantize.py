@@ -12,16 +12,21 @@ paper studies) are faithful:
 
 ``quantize``/``dequantize`` expose the packed representation (used by the
 int8 Pallas shadow matmul kernel); ``simulate_quantization`` returns a
-float tensor carrying the quantization error (used for SEP experiments
-where we only care about numerics, not memory).
+float tensor carrying the quantization error (the numerics oracle).
+``shadow_codes`` is what the SEP shadow holds: every leaf the scheme
+quantizes kept as those same codes and scales (``QWeight``), which the
+model dequantizes a layer at a time, and a routed expert only when a
+token is routed to it.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass, field
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.moe_gemm.packed import NF4_BLOCK, NF4_TABLE
 
@@ -163,6 +168,147 @@ def shadow_params(params, scheme: str):
     return quantize_pytree(params, scheme)
 
 
+# ------------------------------------------------------------ shadow codes
+EXPERT_WEIGHT_NAMES = ("w_gate", "w_up", "w_down")   # one routed expert
+
+
+@jax.tree_util.register_dataclass
+@dataclass(frozen=True)
+class QWeight:
+    """One weight held as its quantized codes: ``dequant()`` gives the
+    values ``simulate_quantization`` gives, element for element.
+
+    ``codes`` keeps the weight's leading axes, so a stacked leaf slices
+    per layer (``lax.scan``) and per expert (``take``).  int8: codes of
+    the weight's shape and one scale per output channel, broadcast over
+    the stacked-layer axis; fp16: the half-precision values; nf4: the
+    64-blocks unfolded as ``lead + (blocks, 64)`` with a scale per block,
+    where ``tail`` is the logical shape past ``lead``."""
+    codes: jax.Array
+    scale: Optional[jax.Array]
+    scheme: str = field(metadata=dict(static=True))
+    dtype: str = field(metadata=dict(static=True))
+    tail: Tuple[int, ...] = field(metadata=dict(static=True))
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        if self.scheme == "nf4":
+            return tuple(self.codes.shape[:-2]) + self.tail
+        return tuple(self.codes.shape)
+
+    def _values(self, codes, scale):
+        if self.scheme == "fp16":
+            w = codes.astype(jnp.float32)
+        elif self.scheme == "int8":
+            w = dequantize_int8(codes, scale)
+        else:
+            lead = tuple(codes.shape[:-2])
+            n = int(np.prod(self.tail))
+            w = (NF4_LEVELS[codes.astype(jnp.int32)] * scale).reshape(
+                lead + (-1,))[..., :n].reshape(lead + self.tail)
+        return w.astype(jnp.dtype(self.dtype))
+
+    def dequant(self):
+        return self._values(self.codes, self.scale)
+
+    def take(self, idx):
+        """Dequantized entries ``idx`` of the leading axis."""
+        if self.scheme == "nf4" and self.codes.ndim == 2:   # flat blocks
+            return self.dequant()[idx]
+        scale = self.scale[idx] if self.scheme == "nf4" else self.scale
+        return self._values(self.codes[idx], scale)
+
+    def block(self, lo: int, n: int):
+        """Dequantized entries ``lo:lo + n`` of the leading axis."""
+        scale = (self.scale[lo:lo + n] if self.scheme == "nf4"
+                 else self.scale)
+        return self._values(self.codes[lo:lo + n], scale)
+
+
+def _quantizable(w) -> bool:
+    return (w.ndim >= 2 and w.size >= _MIN_QUANT_SIZE
+            and jnp.issubdtype(w.dtype, jnp.floating))
+
+
+_absmax = jax.jit(lambda w: jnp.max(jnp.abs(w), axis=tuple(
+    range(w.ndim - 1)), keepdims=True))
+_int8_round = jax.jit(lambda w, scale: jnp.clip(
+    jnp.round(w / scale), -127, 127).astype(jnp.int8))
+_nf4_codes = jax.jit(quantize_nf4)
+_half = jax.jit(lambda w: w.astype(jnp.float16))
+
+
+def _codes(w, scheme: str):
+    """``quantize(w, scheme)``'s codes and scales, with the passes over
+    the whole weight compiled (no full-size temporaries).  The int8
+    scale is the same eager arithmetic as ``quantize_int8``: compiled,
+    XLA would divide by 127 as a multiply by its reciprocal."""
+    if scheme == "int8":
+        scale = jnp.maximum(_absmax(w), 1e-8) / 127.0
+        return _int8_round(w, scale), scale.astype(jnp.float32)
+    if scheme == "nf4":
+        return _nf4_codes(w)
+    return _half(w), None
+
+
+def quantize_leaf(w, scheme: str, stacked: bool = False):
+    """``w`` as the shadow holds it: a ``QWeight`` of its codes, or, for
+    a leaf the scheme leaves alone, ``w`` itself.  ``stacked``: the
+    leading axis is the stacked-layer axis every piece must keep.  A
+    host ``numpy`` leaf is quantized on the device and only its codes
+    stay there.  An nf4 leaf whose blocks straddle its leading slices
+    keeps its dequantized values instead."""
+    if scheme in ("fp32", "none") or not _quantizable(w):
+        return w
+    codes, scale = _codes(jnp.asarray(w), scheme)
+    dtype = str(w.dtype)
+    if scheme == "int8":
+        if stacked:
+            scale = jnp.broadcast_to(scale, (w.shape[0],)
+                                     + scale.shape[1:])
+        return QWeight(codes, scale, scheme, dtype, ())
+    if scheme == "fp16":
+        return QWeight(codes, None, scheme, dtype, ())
+    lead = max((k for k in range(1, w.ndim)
+                if int(np.prod(w.shape[k:])) % NF4_BLOCK == 0), default=0)
+    if lead == 0:
+        return (dequantize_nf4(codes, scale, w.shape).astype(w.dtype)
+                if stacked else QWeight(codes, scale, scheme, dtype,
+                                        tuple(w.shape)))
+    blocks = int(np.prod(w.shape[lead:])) // NF4_BLOCK
+    head = tuple(w.shape[:lead]) + (blocks,)
+    return QWeight(codes.reshape(head + (NF4_BLOCK,)),
+                   scale.reshape(head + (1,)), scheme, dtype,
+                   tuple(w.shape[lead:]))
+
+
+def shadow_codes(params, scheme: str):
+    """The SEP shadow's weights: ``params`` with every leaf the scheme
+    quantizes held as a ``QWeight`` (one leaf at a time, so a host leaf
+    never has more than itself on the device)."""
+    out = {}
+    for k, v in params.items():
+        stacked = k == "layers"
+        out[k] = jax.tree.map(
+            lambda w, s=stacked: quantize_leaf(w, scheme, stacked=s), v)
+    return out
+
+
+def materialize(tree, keep_routed: bool = True):
+    """``tree`` with each ``QWeight`` dequantized, except (with
+    ``keep_routed``) a routed-expert layer's expert weights, which the
+    MoE dispatch dequantizes only where tokens are routed."""
+    if isinstance(tree, QWeight):
+        return tree.dequant()
+    if isinstance(tree, dict):
+        keep = keep_routed and "router" in tree
+        return {k: v if keep and k in EXPERT_WEIGHT_NAMES
+                else materialize(v, keep_routed) for k, v in tree.items()}
+    if isinstance(tree, (tuple, list)):
+        return type(tree)(materialize(v, keep_routed) for v in tree)
+    return tree
+
+
 def shadow_nbytes(params, scheme: str) -> int:
     """Deployed byte footprint of ``shadow_params(params, scheme)``.
 
@@ -174,14 +320,18 @@ def shadow_nbytes(params, scheme: str) -> int:
     their real ``nbytes``.  This replaces the old hard-coded
     ``{fp16: 0.5, int8: 0.25, nf4: 0.125}`` fraction table, which was
     wrong whenever any leaf skipped quantization (and ignored scale
-    payloads entirely).
+    payloads entirely).  A ``QWeight`` (``shadow_codes``) is charged as
+    the leaf it stands for.
     """
     from .transport import get_codec             # deferred: avoids cycle
     codec = get_codec("fp32" if scheme in ("fp32", "none") else scheme)
     total = 0
-    for w in jax.tree.leaves(params):
-        if w.ndim >= 2 and w.size >= _MIN_QUANT_SIZE and jnp.issubdtype(
-                w.dtype, jnp.floating):
+    for w in jax.tree.leaves(params,
+                             is_leaf=lambda x: isinstance(x, QWeight)):
+        if isinstance(w, QWeight):
+            total += codec.packed_nbytes(
+                w.shape, elem_bytes=jnp.dtype(w.dtype).itemsize)
+        elif _quantizable(w):
             total += codec.packed_nbytes(tuple(int(s) for s in w.shape),
                                          elem_bytes=w.dtype.itemsize)
         else:

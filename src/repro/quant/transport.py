@@ -50,13 +50,11 @@ import numpy as np
 
 from repro.models.config import MOE_FF, ModelConfig
 
-from .quantize import (NF4_BLOCK, dequantize_int8, dequantize_nf4,
-                       pack_nf4_codes, quantize_int8, quantize_nf4,
-                       unpack_nf4_codes)
+from .quantize import (EXPERT_WEIGHT_NAMES, NF4_BLOCK, dequantize_int8,
+                       dequantize_nf4, pack_nf4_codes, quantize_int8,
+                       quantize_nf4, unpack_nf4_codes)
 
 SCHEMES = ("fp32", "fp16", "int8", "nf4")
-
-EXPERT_WEIGHT_NAMES = ("w_gate", "w_up", "w_down")
 
 
 @dataclass(frozen=True)

@@ -271,8 +271,17 @@ class ServingLoop:
             self._admit_seq += 1
             state.generated.append(int(token[0]))
             if eng.shadow is not None:
-                state.shadow_state = eng.shadow.prefill_state(batch, cache_len)
+                state.shadow_state = self._shadow_start(batch, cache_len)
             return state
+
+    def _shadow_start(self, batch, cache_len: int) -> dict:
+        """A request's first shadow state.  Where the alignment policy
+        overwrites both the shadow's token and its caches at the first
+        peek, a shadow prefill would be thrown away, so none runs: the
+        peek starts from the main model's state."""
+        if self.policy.align_token_at(1) and self.policy.align_kv_at(1):
+            return {"caches": None, "pos": None, "token": None}
+        return self.engine.shadow.prefill_state(batch, cache_len)
 
     def _pool_fits_prompt(self, req: Request) -> bool:
         pool = self.kv_pool
@@ -359,7 +368,7 @@ class ServingLoop:
         state.generated.append(int(token[0]))
         state.prefilling = False
         if eng.shadow is not None:
-            state.shadow_state = eng.shadow.prefill_state(batch, cache_len)
+            state.shadow_state = self._shadow_start(batch, cache_len)
         if state.done:                       # max_new_tokens == 1
             state.finish_s = clock.now
             self._retire(state, queue)
@@ -544,6 +553,13 @@ class ServingLoop:
     @property
     def clock(self) -> DecodeClock:
         return self._clock
+
+    def memory_report(self) -> dict:
+        """The engine's memory report, counting the session's live
+        request state (caches, shadow states and peeks) as its caches."""
+        live = [(s.cache_list, s.shadow_state, s.pending)
+                for s in self._queue.active]
+        return self.engine.memory_report(caches=live)
 
     def tick(self) -> bool:
         """One iteration of the serving loop (the body of ``run``'s

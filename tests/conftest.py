@@ -75,3 +75,18 @@ def tiny_ssm(**kw):
                 ssm_state=16, ssm_head_dim=16, ssm_chunk=4)
     base.update(kw)
     return ModelConfig(**base)
+
+
+def tiny_hybrid(**kw):
+    """One Granite-4.0-H period: Mamba-2 mixers with a NoPE attention
+    layer at index 5, a routed FFN with a shared expert on every layer,
+    the four Granite multipliers."""
+    base = dict(name="t-hybrid", family="hybrid", num_layers=10,
+                d_model=64, num_heads=4, num_kv_heads=2, d_ff=32,
+                d_expert=32, d_shared=48, vocab_size=97, num_experts=8,
+                top_k=3, ssm_state=16, ssm_head_dim=32, ssm_chunk=8,
+                attn_every=10, attn_offset=5, rope_fraction=0.0,
+                embedding_multiplier=12.0, attention_multiplier=0.0625,
+                residual_multiplier=0.22, logits_scaling=16.0)
+    base.update(kw)
+    return ModelConfig(**base)

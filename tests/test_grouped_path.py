@@ -186,7 +186,7 @@ def test_shadow_node_bytes_match_real_packed_sizes(scheme):
     full-precision leaf (norms, small vectors), its real ``nbytes``.
     The retired fraction table got this wrong whenever a leaf skipped
     quantization."""
-    from repro.quant import get_codec
+    from repro.quant import get_codec, shadow_params
     from repro.quant.quantize import _MIN_QUANT_SIZE
 
     cfg = tiny_moe(num_layers=2)
@@ -195,7 +195,9 @@ def test_shadow_node_bytes_match_real_packed_sizes(scheme):
                       physical_loading=False)
     codec = get_codec(scheme)
     expect = skipped = 0
-    for w in jax.tree.leaves(eng.shadow.params):
+    # the shadow holds codes; its deployed footprint is that of the
+    # quantized tree they stand for, leaf by leaf
+    for w in jax.tree.leaves(shadow_params(params, scheme)):
         if w.ndim >= 2 and w.size >= _MIN_QUANT_SIZE and jnp.issubdtype(
                 w.dtype, jnp.floating):
             expect += codec.pack(w).nbytes          # real packed bytes
