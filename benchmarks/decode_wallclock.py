@@ -62,9 +62,16 @@ def tiny_model():
     return cfg, init_params(cfg, jax.random.PRNGKey(0))
 
 
+def _inline(mode):
+    """The grouped-vs-loop comparison fetches inline on both sides (the
+    loop path has no executor)."""
+    return "sync" if mode == "grouped" else None
+
+
 def _engine(cfg, params, mode):
     return ODMoEEngine(cfg, params, n_workers=8, predictor="sep",
-                       shadow_scheme="int8", wave_compute=mode)
+                       shadow_scheme="int8", wave_compute=mode,
+                       prefetch=_inline(mode))
 
 
 # ------------------------------------------------------- single stream
@@ -92,7 +99,8 @@ def single_stream_tps(cfg, params, mode, n_tokens) -> float:
     def run():
         eng = _PrefillTimedEngine(
             cfg, params, n_workers=8, predictor="sep",
-            shadow_scheme="int8", wave_compute=mode)
+            shadow_scheme="int8", wave_compute=mode,
+            prefetch=_inline(mode))
         shadow_reset = eng.shadow.reset
 
         def timed_reset(b, cache_len):
@@ -148,7 +156,7 @@ def async_decode_point(cfg, params, predictor, n_tokens,
             cfg, params, predictor=predictor, shadow_scheme="int8",
             wave_compute="grouped", transport="int8",
             profiles=uniform_profiles(8, capacity=2),
-            prefetch=prefetch, residency=residency)
+            prefetch=prefetch, residency=residency, peek_horizon=0)
         dts = []
         inner = eng.decode_batch
 
@@ -160,17 +168,17 @@ def async_decode_point(cfg, params, predictor, n_tokens,
 
         eng.decode_batch = timed_decode
         toks, _ = eng.generate(batch, n_tokens, AlignmentPolicy(1, 1))
-        rep = eng.prefetch_report() if prefetch else {}
+        rep = eng.prefetch_report() if prefetch == "thread" else {}
         eng.close()
         assert np.array_equal(np.asarray(toks), ref), \
             f"async decode diverged ({predictor}, {prefetch}, {residency})"
         return min(dts[1:]), rep
 
-    for args in ((None, None), ("thread", "lru")):
+    for args in (("sync", None), ("thread", "lru")):
         run(*args)                     # warm-up: compile at these shapes
     t_sync, t_async, rep = 9e9, 9e9, {}
     for _ in range(repeats):           # interleaved best-of-N: the two
-        t_sync = min(t_sync, run(None, None)[0])      # paths see the
+        t_sync = min(t_sync, run("sync", None)[0])    # paths see the
         dt, rep = run("thread", "lru")                # same host noise
         t_async = min(t_async, dt)
     pf = rep.get("prefetch_prefetched", 0)
@@ -217,7 +225,8 @@ def spec_over_async_point(cfg, params, n_tokens, repeats) -> dict:
             cfg, params, predictor="sep", shadow_scheme="int8",
             wave_compute="grouped", transport="int8",
             profiles=uniform_profiles(8, capacity=2),
-            prefetch="thread", residency="lru", speculate=speculate)
+            prefetch="thread", residency="lru", speculate=speculate,
+            peek_horizon=0)
         draft_acc = [0.0]              # drafting time since last wave
         costs, commits = [], []
         for name in ("step", "step_state", "rollout_states"):

@@ -62,8 +62,9 @@ def serve_point(cfg, params, rate: float, policy: str, n: int,
                       # the experts that physically shipped (lr.shipped)
                       profiles=(uniform_profiles(8, capacity=2)
                                 if use_async else None),
-                      prefetch="thread" if use_async else None,
-                      residency="lru" if use_async else None)
+                      prefetch="thread" if use_async else "sync",
+                      residency="lru" if use_async else None,
+                      peek_horizon=0)
     loop = ServingLoop(eng, max_batch=max_batch,
                        composer=BatchComposer(max_batch, policy))
     res = loop.run(make_traffic(cfg, n, rate, max_new=tokens))
@@ -80,7 +81,7 @@ def serve_point(cfg, params, rate: float, policy: str, n: int,
                             / max(rep["total_tokens"], 1)),
         "bytes_moved": eng.slots.bytes_moved,
     })
-    if res.prefetch_stats is not None:
+    if use_async:
         ps = res.prefetch_stats
         rep["rehit_rate"] = ps["rehit_rate"]
         fetched = (ps.get("prefetch_prefetched", 0)

@@ -322,6 +322,12 @@ def host_held_experts(cfg: ModelConfig, params) -> bool:
                for n in EXPERT_WEIGHT_NAMES)
 
 
+# MoE layers whose predicted experts may be in flight at once: the layer
+# being served and the next one.  Deeper windows hold more experts on
+# the device beside the slots (PERF.md, Findings).
+PEEK_HORIZON = 2
+
+
 class ODMoEEngine:
     def __init__(self, cfg: ModelConfig, params, *, n_workers: int = 8,
                  group_size: int = 0, predictor: str = "sep",
@@ -329,7 +335,7 @@ class ODMoEEngine:
                  physical_loading: bool = True, seed: int = 0,
                  profiles=None, faults=None, transport=None,
                  wave_compute: str = "grouped", prefetch=None,
-                 residency=None, peek_horizon: int = 0,
+                 residency=None, peek_horizon: int = PEEK_HORIZON,
                  speculate: int = 1, sched=None, store=None,
                  gate_stats=None, compute_vs_ship=None,
                  packed_slots: bool = False, keep_logits: bool = False):
@@ -389,6 +395,7 @@ class ODMoEEngine:
         # move fewer bytes.
         self.transport = resolve_policy(transport)
         self.moe_layers = moe_layer_indices(cfg)
+        self._moe_index = {li: i for i, li in enumerate(self.moe_layers)}
         # routed experts given as host ``numpy`` leaves stay there: the
         # store aliases them, decode loads them into worker slots and
         # prefill streams them a block at a time (``_prefill_streamed``)
@@ -464,9 +471,12 @@ class ODMoEEngine:
                        else transport_params(cfg, params, self.transport,
                                              packed=self.store.get_packed))
         # opportunistic residency + async prefetch (repro.core.prefetch).
-        # Defaults (None) keep the historical cacheless synchronous
-        # engine bit-for-bit: release degrades to evict, loads fetch
-        # inline.
+        # ``residency=None`` keeps the cacheless rule: release degrades
+        # to evict.  ``prefetch=None`` fetches predicted experts ahead on
+        # the process's loader pool when loads are physical (bookkeeping
+        # loads and the retired loop path fetch inline); ``"sync"`` is
+        # the inline oracle.  Either way the commits, the event log and
+        # the tokens are the same.
         self.residency = resolve_residency(residency)
         self.slots = WorkerSlots(self.store, n_workers,
                                  physical=physical_loading,
@@ -474,7 +484,8 @@ class ODMoEEngine:
                                                   None),
                                  residency=self.residency,
                                  packed_resident=packed_slots)
-        executor = make_executor(prefetch)
+        executor = make_executor(
+            prefetch, ahead=physical_loading and wave_compute == "grouped")
         self.prefetch: Optional[PrefetchExecutor] = (
             None if executor is None
             else PrefetchExecutor(self.store, executor,
@@ -742,12 +753,13 @@ class ODMoEEngine:
                 self.faults.apply(step_idx, self.sched.state, self.slots)
             x = _embed_token(cfg)(self.params, token)
             pending: Dict[int, np.ndarray] = dict(preds)
-            # SEP predictions cover the whole token up front: queue their
-            # fetches NOW so transfers overlap all the compute before each
-            # layer's wave boundary (the peek horizon bounds the window)
+            # SEP predictions cover the whole token up front: queue the
+            # first layers' fetches NOW so transfers overlap the compute
+            # before each layer's wave boundary; each layer served queues
+            # the next (the peek horizon bounds the window)
             if self.prefetch is not None and pending:
-                self.prefetch.enqueue(step_idx, 0, pending,
-                                      skip=self._resident_skip())
+                self.prefetch.enqueue(step_idx, -1, pending,
+                                      select=self._fetch_ahead)
             moe_i = -1
             for li, kinds in enumerate(cfg.layer_kinds()):
                 stack, r = self._at[li]
@@ -810,8 +822,8 @@ class ODMoEEngine:
                         + jnp.arange(s_w, dtype=pos.dtype)).reshape(-1)
             pending: Dict[int, np.ndarray] = dict(preds)
             if self.prefetch is not None and pending:
-                self.prefetch.enqueue(step_idx, 0, pending,
-                                      skip=self._resident_skip())
+                self.prefetch.enqueue(step_idx, -1, pending,
+                                      select=self._fetch_ahead)
             spec_caches: Dict[int, dict] = {}
             moe_i = -1
             for li, kinds in enumerate(cfg.layer_kinds()):
@@ -851,15 +863,24 @@ class ODMoEEngine:
             rec.committed = int(jnp.sum(c))
             return verified, c, cache_list, pos + c
 
-    def _resident_skip(self):
-        """Prefetch skip predicate under residency: an expert that is
-        still resident somewhere will re-hit, so fetching it again is
-        pure waste.  (Cacheless engines never have cross-layer
-        residents, so the predicate is only built when residency is
-        on.)"""
-        if self.residency is None:
-            return None
-        return lambda layer, e: self.slots.worker_with(layer, e) is not None
+    def _fetch_ahead(self, layer: int, experts: List[int]) -> List[int]:
+        """The predicted experts of ``layer`` worth fetching ahead: those
+        ``_serve_and_compute`` will place onto load slots, as the fleet
+        stands now.  Under residency an expert still resident re-hits
+        in place and holds its slot; the overflow beyond the fleet's
+        slots reloads on demand, so fetched ahead it would only sit on
+        the device until the token ends."""
+        rest: List[int] = []
+        reserved: Dict[int, int] = {}
+        for e in experts:
+            w = (self.slots.worker_with(layer, e)
+                 if self.residency is not None else None)
+            if w is None:
+                rest.append(e)
+            else:
+                reserved[w] = reserved.get(w, 0) + 1
+        return [e for e, _ in self.sched.place(self._moe_index[layer], rest,
+                                               reserved)]
 
     def _moe_bookkeeping(self, step_idx, li, moe_i, pending, true, h,
                          topk_gate, shared, x, rec: TokenRecord):
@@ -879,10 +900,11 @@ class ODMoEEngine:
             if self.rand is not None:
                 pending[li] = self.rand.predict(li, b)
             if self.prefetch is not None and pending:
-                # on-the-fly predictors only just produced this layer's (and
-                # lookahead) predictions; queue whatever is new in-window
+                # slide the window one layer on; on-the-fly predictors
+                # only just produced this layer's (and lookahead)
+                # predictions: queue whatever is new in-window
                 self.prefetch.enqueue(step_idx, li, pending,
-                                      skip=self._resident_skip())
+                                      select=self._fetch_ahead)
             pred = pending.get(li)
             lr, y = self._serve_and_compute(
                 step_idx, li, moe_i, pred, true, h, np.asarray(topk_gate))

@@ -29,8 +29,11 @@ hundreds of seeds.
 
 ``PrefetchExecutor`` is the SEP-peek-driven load queue: the engine
 enqueues predicted experts for every MoE layer within the peek horizon
-as soon as predictions exist (for the SEP shadow: all layers at once,
-at token start), and joins per-layer at the wave boundary.
+as soon as predictions exist (for the SEP shadow: the first layers at
+token start, then one layer further at each layer served), and joins
+per-layer at the wave boundary.  An engine that loads physically and is
+given no executor fetches on ``loader_pool()``, the process's one pool
+of loader threads, so the copies run while the decode thread computes.
 
 Opportunistic residency (``LRUResidency`` / ``GateStatsResidency``)
 rides on ``WorkerSlots.release``: after a layer computes, its workers'
@@ -46,14 +49,32 @@ from __future__ import annotations
 
 import functools
 import random
+import threading
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
                     Tuple)
 
 from .predictor import layers_within_horizon
+from .spans import span
 
 Key = Tuple[int, int, int]           # (step, layer, expert)
+
+LOADER_THREADS = 4
+_loader_pool: Optional[ThreadPoolExecutor] = None
+_loader_lock = threading.Lock()
+
+
+def loader_pool() -> ThreadPoolExecutor:
+    """The process's one pool of loader threads, made on first use and
+    shared by every ``ThreadedExecutor`` (a test session builds
+    hundreds of engines; they add no threads)."""
+    global _loader_pool
+    with _loader_lock:
+        if _loader_pool is None:
+            _loader_pool = ThreadPoolExecutor(
+                max_workers=LOADER_THREADS, thread_name_prefix="loader")
+        return _loader_pool
 
 
 # ------------------------------------------------------------ executors
@@ -87,20 +108,24 @@ class SyncExecutor:
                 n += 1
         return n
 
+    def ready(self, keys: Sequence[Key]) -> int:
+        return 0                     # nothing runs before its collect
+
     def close(self) -> None:
         self._pending.clear()
 
 
 class ThreadedExecutor:
-    """Real background fetches on a thread pool.  ``collect`` joins the
-    demanded futures (the wave boundary); everything else keeps
-    transferring while the main thread runs grouped-FFN compute."""
+    """Real background fetches on ``loader_pool()``.  ``collect`` joins
+    the demanded futures (the wave boundary); everything else keeps
+    transferring while the main thread runs grouped-FFN compute.  The
+    pool is the process's: ``close`` drops only this executor's
+    fetches."""
 
     kind = "thread"
 
-    def __init__(self, max_workers: int = 4) -> None:
-        self._pool = ThreadPoolExecutor(max_workers=max_workers,
-                                        thread_name_prefix="prefetch")
+    def __init__(self) -> None:
+        self._pool = loader_pool()
         self._futs: Dict[Key, object] = {}
 
     def submit(self, key: Key, fn: Callable[[], object]) -> None:
@@ -124,9 +149,12 @@ class ThreadedExecutor:
                 n += 1
         return n
 
+    def ready(self, keys: Sequence[Key]) -> int:
+        return sum(1 for k in keys
+                   if k in self._futs and self._futs[k].done())
+
     def close(self) -> None:
-        self._pool.shutdown(wait=True, cancel_futures=True)
-        self._futs.clear()
+        self.discard(list(self._futs))
 
 
 class ChaosExecutor:
@@ -202,23 +230,30 @@ class ChaosExecutor:
                 n += 1
         return n
 
+    def ready(self, keys: Sequence[Key]) -> int:
+        return sum(1 for k in keys if k in self._done)
+
     def close(self) -> None:
         self._pending.clear()
         self._done.clear()
 
 
-def make_executor(spec):
-    """``None`` | ``'sync'`` | ``'thread'`` | an executor instance."""
+def make_executor(spec, ahead: bool = False):
+    """``None`` | ``'sync'`` | ``'thread'`` | an executor instance.
+    ``None`` fetches ahead on the loader pool where ``ahead`` (an engine
+    that loads physically on the grouped path) and keeps no executor
+    elsewhere; ``'thread'`` asks for the loader pool outright."""
     if spec is None:
-        return None
+        return ThreadedExecutor() if ahead else None
     if isinstance(spec, str):
         if spec == "sync":
             return SyncExecutor()
         if spec == "thread":
             return ThreadedExecutor()
         raise ValueError(f"unknown prefetch executor {spec!r}")
-    if not (hasattr(spec, "submit") and hasattr(spec, "collect")):
-        raise TypeError("prefetch executor needs submit()/collect()")
+    if not all(hasattr(spec, m) for m in ("submit", "collect", "ready")):
+        raise TypeError("prefetch executor needs submit()/collect()/"
+                        "ready()")
     return spec
 
 
@@ -234,6 +269,11 @@ class PrefetchExecutor:
     reload set out through the executor so even misses transfer in
     parallel; ``finish_token`` retires stale tasks (predictions that
     never became loads — mispredicts and residency re-hits).
+
+    Every fetch runs inside an ``odmoe.expert_load`` span on whatever
+    thread runs it, with ``ahead=1`` when a prediction submitted it
+    before its layer was served; each join is an ``odmoe.expert_wait``
+    span on the thread that commits.
     """
 
     def __init__(self, store, executor, *, horizon: int = 0,
@@ -247,29 +287,55 @@ class PrefetchExecutor:
         self.stats = {"submitted": 0, "demand_fetches": 0, "prefetched": 0,
                       "inline": 0, "stale": 0}
 
-    def _fetch_fn(self, layer: int, expert: int):
-        fetch = (self.store.device_shard if self.packed
-                 else self.store.unpack_shard)
-        return functools.partial(fetch, layer, expert, self.physical)
+    def _fetch_fn(self, layer: int, expert: int, predicted: bool,
+                  ahead: bool):
+        fetch = functools.partial(
+            self.store.device_shard if self.packed
+            else self.store.unpack_shard, layer, expert, self.physical)
+        args = dict(layer=layer, expert=expert,
+                    nbytes=self.store.packed_bytes(layer, expert),
+                    predicted=int(predicted), ahead=int(ahead))
+
+        def run():
+            with span("expert_load", **args):
+                return fetch()
+        return run
 
     def enqueue(self, step: int, current_layer: int,
                 pending: Mapping[int, object],
-                skip: Optional[Callable[[int, int], bool]] = None) -> None:
-        """Submit fetches for every predicted expert of every MoE layer
-        within the horizon.  ``skip`` (residency) suppresses fetches for
-        experts that are already resident somewhere — they will re-hit."""
+                select: Optional[Callable[[int, List[int]], List[int]]]
+                = None) -> None:
+        """Submit fetches for the predicted experts of every MoE layer
+        within the horizon, from ``current_layer`` (the layer being
+        served; -1 before the first).  ``select(layer, experts)`` keeps
+        those worth fetching: the engine drops residents that will
+        re-hit and the overflow its schedule cannot place."""
         for tgt in layers_within_horizon(list(pending), current_layer,
                                          self.horizon):
-            pred = pending[tgt]
-            for e in dict.fromkeys(int(x) for x in pred.reshape(-1)):
+            experts = list(dict.fromkeys(
+                int(x) for x in pending[tgt].reshape(-1)))
+            if select is not None:
+                experts = select(tgt, experts)
+            for e in experts:
                 key = (step, tgt, e)
                 if key in self._enqueued:
                     continue
-                if skip is not None and skip(tgt, e):
-                    continue
                 self._enqueued.add(key)
                 self.stats["submitted"] += 1
-                self.executor.submit(key, self._fetch_fn(tgt, e))
+                self.executor.submit(key, self._fetch_fn(
+                    tgt, e, True, tgt > current_layer))
+
+    def _join(self, step: int, layer: int,
+              experts: Sequence[int]) -> Dict[int, object]:
+        queued = [k for k in ((step, layer, int(e)) for e in experts)
+                  if k in self._enqueued]
+        if not queued:
+            return {}
+        with span("expert_wait", experts=len(queued),
+                  ready=self.executor.ready(queued)):
+            got = self.executor.collect(queued)
+        self._enqueued.difference_update(queued)
+        return {k[2]: v for k, v in got.items()}
 
     def collect(self, step: int, layer: int,
                 experts: Sequence[int]) -> Dict[int, object]:
@@ -277,14 +343,10 @@ class PrefetchExecutor:
         Returns ``{expert: payload}`` for fetches that completed; a
         demanded expert with no payload (never enqueued, dropped, or
         deferred by chaos) loads inline at commit."""
-        keys = [(step, layer, int(e)) for e in experts]
-        queued = [k for k in keys if k in self._enqueued]
-        got = self.executor.collect(queued)
-        for k in queued:
-            self._enqueued.discard(k)
+        got = self._join(step, layer, experts)
         self.stats["prefetched"] += len(got)
-        self.stats["inline"] += len(keys) - len(got)
-        return {k[2]: v for k, v in got.items()}
+        self.stats["inline"] += len(experts) - len(got)
+        return got
 
     def fetch_now(self, step: int, layer: int,
                   experts: Sequence[int]) -> Dict[int, object]:
@@ -296,8 +358,9 @@ class PrefetchExecutor:
             if key not in self._enqueued:
                 self._enqueued.add(key)
                 self.stats["demand_fetches"] += 1
-            self.executor.submit(key, self._fetch_fn(layer, int(e)))
-        return self.collect(step, layer, experts)
+            self.executor.submit(key, self._fetch_fn(layer, int(e),
+                                                     False, False))
+        return self._join(step, layer, experts)
 
     def finish_token(self, step: int) -> None:
         """Token boundary: retire fetches that never became loads."""

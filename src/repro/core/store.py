@@ -353,17 +353,19 @@ class WorkerSlots:
             self.residency_stats["evicted_bytes"] += \
                 self._resident_nbytes(victim)
         nbytes = self.store.packed_bytes(layer, expert)
-        # the shipping path's host->device copy (hits move nothing)
-        with span("expert_load", layer=int(layer), expert=int(expert),
-                  nbytes=nbytes, predicted=bool(predicted)):
-            if payload is not None:
-                data = payload
-            elif self.packed_resident:
-                data = self.store.device_shard(layer, expert,
-                                               device=self.physical)
-            else:
-                data = self.store.unpack_shard(layer, expert,
-                                               device=self.physical)
+        data = payload
+        if data is None:
+            # the shipping path's host->device copy, inline (hits move
+            # nothing; a prefetched payload was copied on its loader
+            # thread, under a span of its own)
+            with span("expert_load", layer=int(layer), expert=int(expert),
+                      nbytes=nbytes, predicted=int(predicted), ahead=0):
+                if self.packed_resident:
+                    data = self.store.device_shard(layer, expert,
+                                                   device=self.physical)
+                else:
+                    data = self.store.unpack_shard(layer, expert,
+                                                   device=self.physical)
         self._slot_data[worker][key] = data
         self._occupied[worker].append(key)
         self.stats["loads"] += 1

@@ -96,9 +96,9 @@ def _snapshot(script_key, residency, transport, predictor, executor=None):
 
 @functools.lru_cache(maxsize=None)
 def _baseline(script_key, residency):
-    """The synchronous oracle for one scenario config (no executor)."""
+    """The synchronous oracle for one scenario config (inline fetches)."""
     _, predictor, transport = SCRIPTS[script_key]
-    return _snapshot(script_key, residency, transport, predictor)
+    return _snapshot(script_key, residency, transport, predictor, "sync")
 
 
 def _scenario(seed):
@@ -192,7 +192,7 @@ def test_chaos_spec_schedule(seed):
         commits = tuple(r.committed for r in trace.records)
         return np.asarray(toks), log, eng.slots.bytes_moved, commits
 
-    base = run(None)
+    base = run("sync")
     chaos = run(ChaosExecutor(seed + 500, p_drop=0.3, p_defer=0.3))
     why = (f"spec chaos seed={seed} k={k} residency={residency!r}; "
            f"replay with seed+500={seed + 500}")
@@ -254,10 +254,10 @@ def test_chaos_packed_slots(scheme, seed):
 
     why = (f"packed chaos scheme={scheme} seed={seed} "
            f"residency={residency!r}")
-    sync = run(True)
+    sync = run(True, "sync")
     chaos = run(True, ChaosExecutor(seed + 2000, p_run_ahead=0.5,
                                     p_drop=0.3, p_defer=0.3))
-    base = run(False)
+    base = run(False, "sync")
     ref = _packed_reference(scheme)
     assert np.array_equal(sync[0], ref), f"sync vs greedy: {why}"
     assert np.array_equal(chaos[0], ref), f"chaos vs greedy: {why}"
@@ -297,7 +297,7 @@ def test_serving_chaos_schedule(seed):
                      e.bytes, e.requests) for e in eng.slots.events)
         return res, log, eng.slots.bytes_moved
 
-    base, b_log, b_bytes = serve(None)
+    base, b_log, b_bytes = serve("sync")
     chaos, c_log, c_bytes = serve(ChaosExecutor(seed, p_drop=0.3,
                                                 p_defer=0.3))
     why = f"serving chaos seed={seed} residency={residency!r}"
@@ -317,6 +317,9 @@ class _StubStore:
 
     def unpack_shard(self, layer, expert, device=True):
         return (layer, expert, device)
+
+    def packed_bytes(self, layer, expert):
+        return 0
 
 
 def _drive(executor, rng, journal=None):
@@ -407,7 +410,7 @@ def test_threaded_executor_delivers():
     """Real threads: submitted fetches complete and join correctly (the
     bit-exactness of the full engine path is pinned above; this pins
     the executor plumbing in isolation, including discard)."""
-    ex = ThreadedExecutor(max_workers=2)
+    ex = ThreadedExecutor()
     try:
         keys = [(0, li, e) for li in range(3) for e in range(4)]
         for k in keys:

@@ -1,6 +1,7 @@
 """The serving path's ``odmoe.*`` host spans, read back from a profiler
 trace on the CPU: every phase appears, with its arguments, nested on the
-thread that opened it, and tracing changes no served token."""
+thread that opened it (expert fetches on the loader threads, their joins
+on the decode thread), and tracing changes no served token."""
 import gc
 import glob
 import os
@@ -17,8 +18,9 @@ from repro.serve import Request, ServingLoop
 CFG = tiny_moe(num_layers=3)
 
 SPANS = ("tick", "admit", "prefill", "peek", "shadow_step", "kv_gather",
-         "decode_step", "router_sync", "serve", "expert_load", "wave",
-         "commit", "kv_scatter", "model_clock", "gc")
+         "decode_step", "router_sync", "serve", "expert_load",
+         "expert_wait", "wave", "commit", "kv_scatter", "model_clock",
+         "gc")
 
 
 def _requests():
@@ -94,6 +96,10 @@ def test_router_sync_once_per_moe_layer_and_step(served):
     assert str(steps[0][4]["rids"]).count(";") + 1 == steps[0][4]["rows"]
 
 
+def _decode_threads(events):
+    return {e[3] for e in _named(events, "decode_step")}
+
+
 def test_expert_loads_match_the_counters(served):
     _, _, eng, events = served
     loads = _named(events, "expert_load")
@@ -101,6 +107,54 @@ def test_expert_loads_match_the_counters(served):
     assert sum(e[4]["nbytes"] for e in loads) == eng.slots.bytes_moved
     assert sum(e[4]["predicted"] for e in loads) == \
         eng.slots.stats["predicted_loads"]
+    # the fetches ran on the loader threads, not the decode thread
+    assert {e[3] for e in loads}.isdisjoint(_decode_threads(events))
+
+
+def test_ahead_marks_the_predicted_fetches(served):
+    """Every predicted expert was fetched ahead of its layer (the SEP
+    shadow predicts the whole token before the step); reloads are
+    demand fetches."""
+    _, _, eng, events = served
+    loads = _named(events, "expert_load")
+    assert all(e[4]["ahead"] == e[4]["predicted"] for e in loads)
+    assert sum(e[4]["ahead"] for e in loads) == \
+        eng.slots.stats["predicted_loads"] > 0
+
+
+def test_expert_waits_nest_inside_serve_on_the_decode_thread(served):
+    _, _, _, events = served
+    waits, serves = _named(events, "expert_wait"), _named(events, "serve")
+    assert waits
+    assert all(_inside(w, serves) for w in waits)
+    assert {w[3] for w in waits} <= _decode_threads(events)
+    assert all(0 <= w[4]["ready"] <= w[4]["experts"] for w in waits)
+
+
+def test_fetches_run_between_their_submit_and_their_join(served):
+    """A fetch on a loader thread ends before the decode thread's join
+    in the layer that needs it ends, and starts after whatever submitted
+    it began: the decode step for a fetch ahead, the layer's own
+    ``odmoe.serve`` for a reload's demand fetch."""
+    _, _, _, events = served
+    decode = _decode_threads(events)
+    steps, serves = _named(events, "decode_step"), _named(events, "serve")
+    waits = _named(events, "expert_wait")
+    fetches = [e for e in _named(events, "expert_load")
+               if e[3] not in decode]
+    assert fetches
+    for f in fetches:
+        # the first serve of the fetch's layer that ends after it
+        serve = min((s for s in serves
+                     if s[4]["layer"] == f[4]["layer"] and s[2] >= f[2]),
+                    key=lambda s: s[2])
+        joins = [w for w in waits if _inside(w, [serve])]
+        assert joins and f[2] <= max(w[2] for w in joins)
+        if f[4]["ahead"]:
+            (step,) = [s for s in steps if _inside(serve, [s])]
+            assert step[1] <= f[1]
+        else:
+            assert serve[1] <= f[1]
 
 
 def test_step_phases_nest_inside_decode_step_inside_tick(served):
@@ -110,8 +164,11 @@ def test_step_phases_nest_inside_decode_step_inside_tick(served):
     assert all(_inside(s, ticks) for s in steps)
     for name in ("router_sync", "serve", "wave"):
         assert all(_inside(e, steps) for e in _named(events, name)), name
-    # loads ship inside the layer that needs them
-    assert all(_inside(e, serves) for e in _named(events, "expert_load"))
+    # a load on the decode thread ships inside the layer that needs it
+    # (the loader threads' fetches: test_fetches_run_between_...)
+    decode = _decode_threads(events)
+    assert all(_inside(e, serves) for e in _named(events, "expert_load")
+               if e[3] in decode)
     for name in ("admit", "peek", "kv_gather", "commit", "model_clock"):
         assert all(_inside(e, ticks) for e in _named(events, name)), name
 
